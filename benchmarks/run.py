@@ -95,6 +95,9 @@ def main() -> None:
                     "run under strict numerics) instead of the benchmarks")
     args = ap.parse_args()
 
+    from repro.compile_cache import configure
+
+    configure()
     if args.sanitize:
         sys.exit(_sanitize())
 

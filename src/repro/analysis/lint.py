@@ -353,8 +353,10 @@ def _rule_np_jnp_mixing(mod: ModuleInfo) -> List[Finding]:
                     f"module-level numpy value `{node.id}` (defined at line "
                     f"{mod.np_globals[node.id]}) is referenced inside "
                     f"jit-traced code",
-                    f"define `{node.id}` with jnp (device dtype) so traced "
-                    f"code never closes over host arrays"))
+                    f"make `{node.id}` a Python scalar (or build it with "
+                    f"jnp inside the traced function): traced code never "
+                    f"closes over host arrays, and no device array is "
+                    f"built at import"))
     return out
 
 

@@ -43,10 +43,10 @@ recompile — rare, documented in DESIGN.md §11.
 
 Semantics vs the reference oracle (DESIGN.md §6): SG/FG/PKG routing,
 counts, replicas and window aggregates are exact (timing carries an f32
-epsilon from the on-device relative clock); DC/WC/FISH read frequencies
-at segment granularity from a dense (unbounded) tracker and FISH ticks
-its estimator at segment starts — bounded drift, same class as the
-batched engine's sub-chunking.
+epsilon from the on-device relative clock); DC/WC read frequencies at
+segment granularity and FISH once per FISH epoch, from a dense
+(unbounded) tracker, and FISH ticks its estimator at segment starts —
+bounded drift, same class as the batched engine's sub-chunking.
 """
 
 from __future__ import annotations
@@ -84,7 +84,9 @@ _SEG_CACHE: dict = {}  # static signature -> jitted segment function
 
 _SCHEMES = ("sg", "fg", "pkg", "dc", "wc", "fish")
 _RING_SCHEMES = ("fg", "pkg", "dc", "wc", "fish")
-_BIG_I32 = jnp.int32(2 ** 30)  # device constant: referenced in traced code
+# a Python int, not a jnp scalar: a device constant built at import
+# would claim the accelerator before any caller asked for it
+_BIG_I32 = 2 ** 30
 
 
 def _bucket(n: int) -> int:
@@ -249,24 +251,39 @@ def _route_pkg(a, row):
 
 
 def _tracker_update(a, scheme):
-    """Dense per-key frequency tracker update (whole segment at once,
-    mirroring the batched engine's update-then-classify sub-chunk order).
-    Returns (trk, f per tuple, f_top)."""
+    """Dense per-key frequency tracker update, mirroring the batched
+    engine's update-then-classify sub-chunk order.  Returns (trk, f per
+    tuple, f_top per tuple or scalar)."""
     one = jnp.where(a["valid"], 1.0, 0.0)
     if scheme == "fish":
-        # decay-weighted contributions: a tuple decays once per epoch
-        # boundary after it inside the segment, none before it.  cexp_t
-        # counts the boundaries at or before tuple t (the boundary decay
-        # fires before the tuple is counted); pre_decay covers a segment
-        # starting exactly on a boundary.
+        # one update-then-classify step per FISH epoch inside the segment,
+        # as the batched engine cuts its sub-chunks: a tuple reads the
+        # frequencies of its own epoch, so a key that was hot before a
+        # hot-set change inside the segment stays hot for its earlier
+        # tuples.  cexp_t counts the epoch boundaries at or before tuple
+        # t (the boundary decay fires before the tuple is counted);
+        # pre_decay covers a segment starting exactly on a boundary.
         cexp = ((a["g0"] + jnp.arange(a["valid"].shape[0], dtype=jnp.int32))
                 // a["epoch"]) - (a["g0"] // a["epoch"]) + a["pre_decay"]
-        wgt = one * jnp.power(a["alpha"],
-                              a["c_total"] - cexp.astype(jnp.float32))
-        trk = a["trk"] * jnp.power(a["alpha"], a["c_total"])
-        trk = trk.at[a["keys"]].add(wgt)
-    else:  # dc/wc: no decay (reference tracker runs alpha=1, epoch=2^62)
-        trk = a["trk"].at[a["keys"]].add(one)
+        keys = a["keys"]
+
+        def epoch_step(j, carry):
+            trk, f, f_top = carry
+            trk = trk * jnp.where(j > 0, a["alpha"], 1.0)
+            in_j = a["valid"] & (cexp == j)
+            trk = trk.at[keys].add(jnp.where(in_j, 1.0, 0.0))
+            total = jnp.sum(trk)
+            f = jnp.where(in_j & (total > 0.0), trk[keys] / total, f)
+            f_top = jnp.where(in_j & (total > 0.0), jnp.max(trk) / total,
+                              f_top)
+            return trk, f, f_top
+
+        zeros = jnp.zeros_like(one)
+        return jax.lax.fori_loop(
+            0, a["c_total"].astype(jnp.int32) + 1, epoch_step,
+            (a["trk"], zeros, zeros))
+    # dc/wc: no decay (reference tracker runs alpha=1, epoch=2^62)
+    trk = a["trk"].at[a["keys"]].add(one)
     total = jnp.sum(trk)
     f = jnp.where(total > 0.0, trk[a["keys"]] / total, 0.0)
     f_top = jnp.where(total > 0.0, jnp.max(trk) / total, 0.0)
@@ -308,7 +325,7 @@ def _route_fish(a, row):
     """FISH: Alg. 1 (dense decayed tracker) + Alg. 2 (CHK with monotone
     memory M_k) + Alg. 3 (per-tuple Eq. 2 wait-time argmin against the
     estimator state) — the per-tuple oracle's selection with frequencies
-    read at segment granularity."""
+    read once per FISH epoch."""
     trk, f, f_top = _tracker_update(a, "fish")
     hot = (f > a["theta"]) & (f > 0.0) & (f_top > 0.0)
     ratio = jnp.maximum(f_top / jnp.maximum(f, 1e-30), 1.0)
@@ -786,9 +803,9 @@ class FusedEdgeRunner:
         """Per-epoch FISH timeline (telemetry-enabled only): hot-set size
         and churn read off the *device* tracker after a segment that
         crossed one or more epoch boundaries, plus the per-worker
-        imbalance at that instant.  ``np.asarray`` of a CPU jax buffer is
-        a zero-copy view, so this costs one small reduction per crossed
-        epoch batch, never per tuple."""
+        imbalance at that instant.  ``np.asarray`` copies the tracker to
+        the host (a view only on the CPU backend), one read per segment
+        that crossed an epoch, never per tuple."""
         epoch_idx = self._fish_epoch_idx
         self.tel.ctx.epoch_idx = epoch_idx
         trk = np.asarray(self.trk)[:-1]  # drop the phantom padding row
@@ -822,6 +839,8 @@ class FusedEdgeRunner:
         self._c_pane_flushes.add(1)
         flush_span = self.tel.tracer.span("fused.pane_flush", cat="fused",
                                           pane_fed=self.pane_fed)
+        # device-to-host copies of the whole count and value planes
+        # (w1 x kcap1 each), however few entries are live
         cnt = np.asarray(self.pane_cnt)
         tab = np.asarray(self.pane_tab).reshape(-1, 2)
         last = np.asarray(self.pane_last)
@@ -863,9 +882,9 @@ class FusedEdgeRunner:
             new = dev[:-1, :-1] & ~self._repl_synced[:-1, :-1]
             for k, w in zip(*np.nonzero(new)):
                 grouper.replicas.setdefault(int(k), set()).add(int(w))
-            # asarray of a CPU device buffer is a view, and self.repl is
-            # donated to the next launch — copy before the buffer is
-            # reused
+            # on the CPU backend asarray is a view of the device buffer,
+            # which the next launch takes by donation — copy it (on a TPU
+            # asarray already copied device to host)
             self._repl_synced = dev.copy()
             self._repl_dirty = False
 

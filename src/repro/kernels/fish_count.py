@@ -21,6 +21,7 @@ shrink (one decay pass per epoch instead of per tuple).
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -28,7 +29,23 @@ from jax.experimental import pallas as pl
 
 __all__ = ["fish_count", "fish_epoch_count"]
 
-_BLOCK_N = 1024  # tokens per grid step (VMEM tile)
+_BLOCK_N = 1024  # tokens per grid step (VMEM tile), at most
+#: elements one grid step's compare matrices may hold: 4 MiB at 32 bits,
+#: a quarter of the 16 MiB of VMEM a TPU kernel may scope by default
+_VMEM_ELEMS = 1 << 20
+
+
+def fit_block_n(width: int, block_n: Optional[int] = None) -> int:
+    """Tokens per grid step such that the ``(block_n, width)`` compare
+    matrices stay inside :data:`_VMEM_ELEMS`: the largest power of two
+    that is at most ``block_n`` and :data:`_BLOCK_N` and fits, never
+    below one sublane tile of 8.  Sums over blocks are exact integer
+    counts, so the choice never changes a result."""
+    b = _BLOCK_N if block_n is None else min(int(block_n), _BLOCK_N)
+    b = 1 << (max(b, 8).bit_length() - 1)
+    while b > 8 and b * width > _VMEM_ELEMS:
+        b //= 2
+    return b
 
 
 def _fish_count_kernel(table_ref, keys_ref, counts_ref, matched_ref):
@@ -51,7 +68,7 @@ def fish_count(
     table_keys: jnp.ndarray,
     batch_keys: jnp.ndarray,
     *,
-    block_n: int = _BLOCK_N,
+    block_n: Optional[int] = None,
     interpret: bool = False,
 ):
     """Blocked epoch match-and-count.
@@ -59,10 +76,12 @@ def fish_count(
     table_keys: (K,) int32, -1 marks empty slots.  K should be a multiple of
                 128 for TPU lane alignment (the wrapper in ops.py pads).
     batch_keys: (N,) int32 tuple/key ids (>= 0).
+    block_n:    tokens per grid step; capped by :func:`fit_block_n`.
     returns:    counts (K,) float32, matched (N,) bool.
     """
     k = table_keys.shape[0]
     n = batch_keys.shape[0]
+    block_n = fit_block_n(k, block_n)
     n_pad = -n % block_n
     keys2d = jnp.pad(batch_keys, (0, n_pad), constant_values=-2).reshape(-1, 1)
     table2d = table_keys.reshape(1, k)
@@ -105,11 +124,20 @@ def _fish_epoch_kernel(alpha, block_n, table_ref, counts_ref, keys_ref,
 
     @pl.when(step == 0)
     def _init():
-        # inter-epoch TimeDecayingUpdate fused into the same launch
-        new_counts_ref[...] = counts_ref[...] * jnp.float32(alpha)
+        new_counts_ref[...] = jnp.zeros_like(new_counts_ref)
 
+    # the epoch delta accumulates as exact integer counts, and the decayed
+    # counters join it once at the last step, so the result is the
+    # oracle's alpha*counts + delta whatever block_n is
     new_counts_ref[...] += jnp.sum(eq.astype(jnp.float32), axis=0,
                                    keepdims=True)
+
+    @pl.when(step == pl.num_programs(0) - 1)
+    def _decay():
+        # inter-epoch TimeDecayingUpdate fused into the same launch
+        new_counts_ref[...] = (counts_ref[...] * jnp.float32(alpha)
+                               + new_counts_ref[...])
+
     matched_ref[...] = jnp.any(eq, axis=1, keepdims=True).astype(jnp.int32)
 
     # candidate epoch histogram: occurrences of each token's key within the
@@ -136,7 +164,7 @@ def fish_epoch_count(
     batch_keys: jnp.ndarray,
     *,
     alpha: float,
-    block_n: int = _BLOCK_N,
+    block_n: Optional[int] = None,
     interpret: bool = False,
 ):
     """One fused launch for a whole epoch (ISSUE 1 tentpole):
@@ -160,11 +188,17 @@ def fish_epoch_count(
                  lane alignment — ops.py pads).
     table_counts:(K,) float32 decayed counters.
     batch_keys:  (N,) int32 key ids (>= 0).
+    block_n:     tokens per grid step; capped by :func:`fit_block_n` over
+                 both compare matrices.
     returns:     new_counts (K,) f32 = alpha*counts + epoch delta,
                  matched (N,) bool, cand_count (N,) f32, is_first (N,) bool.
     """
     k = table_keys.shape[0]
     n = batch_keys.shape[0]
+    # each step holds a (block_n, K) and a (block_n, n_tot) compare
+    # matrix; n_tot is n padded to the block, at most n rounded up to
+    # _BLOCK_N
+    block_n = fit_block_n(k + (-(-n // _BLOCK_N) * _BLOCK_N), block_n)
     n_pad = -n % block_n
     keys2d = jnp.pad(batch_keys, (0, n_pad), constant_values=-2).reshape(-1, 1)
     all2d = keys2d.reshape(1, -1)

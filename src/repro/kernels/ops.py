@@ -7,7 +7,7 @@ on TPU the same BlockSpecs compile to Mosaic.
 
 from __future__ import annotations
 
-import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -25,7 +25,7 @@ def _interpret() -> bool:
 
 
 def fish_count(table_keys: jnp.ndarray, batch_keys: jnp.ndarray, *,
-               block_n: int = 1024):
+               block_n: Optional[int] = None):
     """Epoch match-and-count; pads the table to lane width (128)."""
     k = table_keys.shape[0]
     k_pad = -k % 128
@@ -38,7 +38,7 @@ def fish_count(table_keys: jnp.ndarray, batch_keys: jnp.ndarray, *,
 
 def fish_epoch_count(table_keys: jnp.ndarray, table_counts: jnp.ndarray,
                      batch_keys: jnp.ndarray, *, alpha: float,
-                     block_n: int = 1024):
+                     block_n: Optional[int] = None):
     """Fused epoch pass (decay + match-count + candidate histogram).
 
     Pads the table to lane width (128; empty slots key=-1, count=0) and is
@@ -56,7 +56,7 @@ def fish_epoch_count(table_keys: jnp.ndarray, table_counts: jnp.ndarray,
 
 
 def store_probe(table_keys: jnp.ndarray, batch_keys: jnp.ndarray,
-                batch_vals: jnp.ndarray, *, block_n: int = 1024,
+                batch_vals: jnp.ndarray, *, block_n: Optional[int] = None,
                 impl: str = None):
     """Keyed-state probe/accumulate (ISSUE 6): per-slot int32 (vsum, csum)
     of one routed chunk against a resident slot table, plus per-token hit

@@ -23,14 +23,15 @@ round-trip bit-identically.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-__all__ = ["store_probe"]
+from .fish_count import fit_block_n
 
-_BLOCK_N = 1024  # tokens per grid step (VMEM tile)
+__all__ = ["store_probe"]
 
 
 def _store_probe_kernel(table_ref, keys_ref, vals_ref, vsum_ref, csum_ref,
@@ -58,7 +59,7 @@ def store_probe(
     batch_keys: jnp.ndarray,
     batch_vals: jnp.ndarray,
     *,
-    block_n: int = _BLOCK_N,
+    block_n: Optional[int] = None,
     interpret: bool = False,
 ):
     """Blocked probe/accumulate of one routed chunk against a slot table.
@@ -68,10 +69,13 @@ def store_probe(
     batch_keys: (N,) int32 tuple key ids (>= 0).
     batch_vals: (N,) int32 per-tuple values (``repro.state.window.
                 tuple_values`` folded to int32 — the caller guards range).
+    block_n:    tokens per grid step; capped by :func:`~repro.kernels.
+                fish_count.fit_block_n`.
     returns:    vsum (K,) int32, csum (K,) int32, matched (N,) bool.
     """
     k = table_keys.shape[0]
     n = batch_keys.shape[0]
+    block_n = fit_block_n(k, block_n)
     n_pad = -n % block_n
     keys2d = jnp.pad(batch_keys, (0, n_pad), constant_values=-2).reshape(-1, 1)
     vals2d = jnp.pad(batch_vals, (0, n_pad)).reshape(-1, 1)

@@ -746,7 +746,16 @@ class SimulatorSession(_BaseSession):
         self._n_source += n
         self._src_times.append(ts)
         streams = {SOURCE: (batch.keys, ts, roots, batch.values)}
-        self._pump(streams, lambda r: ts[r - base])
+
+        def src_arrival(r):
+            # a window partial released in this feed anchors on its
+            # worker's last tuple in the window, which an earlier feed may
+            # have brought when the window is longer than the feed
+            if r.size and int(r.min()) < base:
+                return np.concatenate(self._src_times)[r]
+            return ts[r - base]
+
+        self._pump(streams, src_arrival)
         receipt = self._feed_receipt(n, float(ts[-1]))
         tel.ctx.engine_clock = receipt.t_end
         tl = tel.timeline

@@ -122,6 +122,24 @@ def test_fused_drift_schemes_within_bands(scheme, keys, values):
         keys, op, values=values)
 
 
+def test_fused_fish_hot_set_change_inside_a_segment():
+    """The ZF hot-set flip lands inside an 8,192-tuple segment: each tuple
+    reads its own epoch's frequencies, so the keys that were hot before
+    the flip keep their spread for their pre-flip tuples instead of
+    falling back to two choices."""
+    ks = zipf_time_evolving(24_576, num_keys=2_000, z=1.2, flip_head=2_000,
+                            seed=0)
+    src = Source(ks, arrival_rate=1e5)
+    topo = _topo("fish", workers=32)
+    rb = _run("batched", topo, src, feeds=3)
+    rf = _run("fused", topo, src, feeds=3)
+    eb, ef = rb.edges[0], rf.edges[0]
+    assert ef.dispatches == 3
+    assert ef.imbalance <= eb.imbalance + 0.05
+    assert ef.execution_time == pytest.approx(eb.execution_time, rel=0.05)
+    assert ef.memory_overhead == pytest.approx(eb.memory_overhead, rel=0.25)
+
+
 # ---------------------------------------------------------------------------
 # multi-feed with events + payload windows: the full churn protocol
 # ---------------------------------------------------------------------------
@@ -162,11 +180,11 @@ def test_fused_multi_feed_with_events(scheme, keys, values):
 # ---------------------------------------------------------------------------
 
 
-def _merge_topo(scheme, backend="array"):
+def _merge_topo(scheme, backend="array", workers=6):
     op = TopoWindowOp(agg="sum", value="payload", size=1_000,
                       backend=backend)
     return Topology(name="m", stages=(
-        Stage("count", 6, operator=op), Stage("merge", 4)),
+        Stage("count", workers, operator=op), Stage("merge", 4)),
         edges=(Edge("source", "count", config_for(scheme)),
                Edge("count", "merge", config_for("fg"))))
 
@@ -200,6 +218,25 @@ def test_operator_emits_incrementally_per_feed(mode, keys, values):
     assert rep.edges[1].n_tuples > mid
     assert rep.state["count"]["merged"] == direct_aggregate(
         keys, _merge_topo("fg").stages[0].operator, values=values)
+
+
+@pytest.mark.parametrize("mode", ("batched", "fused"))
+def test_windows_longer_than_feeds_keep_e2e_latency(mode, keys, values):
+    """A window partial released in a feed can anchor on a tuple an
+    earlier feed brought: its end-to-end latency is measured from that
+    tuple's arrival, the same as when every window closes inside one
+    feed."""
+    src = Source(keys, arrival_rate=2e4, values=values)
+    # 64 workers: some see their last tuple of a window feeds before it
+    # closes
+    topo = _merge_topo("fg", workers=64)
+    whole = _run(mode, topo, src, feeds=6)  # feed == window
+    split = _run(mode, topo, src, feeds=60)
+    assert split.e2e_latency_avg == pytest.approx(whole.e2e_latency_avg,
+                                                  rel=F32_REL)
+    assert split.e2e_latency_p99 == pytest.approx(whole.e2e_latency_p99,
+                                                  rel=F32_REL)
+    assert split.e2e_latency_avg > 0.0
 
 
 def test_serving_operator_emits_incrementally(keys, values):
@@ -282,6 +319,41 @@ def test_same_bucket_feeds_do_not_retrace(keys, values):
     sess.feed(feeds[3])
     assert feed_fused.TRACE_COUNT == before  # same (1500→2048) bucket
     sess.close()
+
+
+# ---------------------------------------------------------------------------
+# the TPU FIFO form: associative scan == sequential scan
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("lanes", (5, 33, 129))
+@pytest.mark.parametrize("n_pad,m", ((64, 50), (4_096, 4_000),
+                                     (16_384, 16_384)))
+def test_fifo_assoc_matches_scan(n_pad, m, lanes):
+    """``_fifo_assoc`` (the default on TPU) gives the finish times and
+    final busy of ``_fifo_scan``: padding tuples sink into the phantom
+    lane at t=0 as ``run_segment`` pads them, and worker 0 gets exactly
+    one tuple."""
+    import jax.numpy as jnp
+
+    rng = np.random.default_rng(n_pad + lanes)
+    phantom = lanes - 1
+    workers = np.full(n_pad, phantom, np.int32)
+    workers[:m] = rng.integers(1, max(phantom, 2), m)
+    workers[m // 2] = 0
+    t = np.zeros(n_pad, np.float32)
+    t[:m] = np.sort(rng.uniform(0.0, m / 2e4, m)).astype(np.float32)
+    busy = rng.uniform(0.0, 0.05, lanes).astype(np.float32)
+    busy[phantom] = 0.0
+    caps = rng.uniform(2e-5, 4e-3, lanes).astype(np.float32)
+    caps[phantom] = 1.0
+    args = tuple(jnp.asarray(x) for x in (busy, caps, workers, t))
+    b_scan, f_scan = feed_fused._fifo_scan(*args)
+    b_assoc, f_assoc = feed_fused._fifo_assoc(*args)
+    np.testing.assert_allclose(np.asarray(f_assoc), np.asarray(f_scan),
+                               rtol=F32_REL)
+    np.testing.assert_allclose(np.asarray(b_assoc), np.asarray(b_scan),
+                               rtol=F32_REL)
 
 
 def test_bucket_boundaries_are_pow2():

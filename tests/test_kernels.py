@@ -58,6 +58,63 @@ def test_fish_count_empty_table():
 
 
 # ---------------------------------------------------------------------------
+# block size: derived from the table width, never changes a result
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("width,block_n,want", [
+    (128, None, 1024),
+    (4_096, None, 256),          # K=4096: the (block_n, K) matrix fits
+    (4_096 + 16_384, None, 32),  # fish_epoch_count at K=4096, N=16384
+    (128, 100, 64),              # a requested size rounds down to pow2
+    (128, 4_096, 1024),          # and never exceeds the default
+    (1 << 20, None, 8),          # never below one sublane tile
+])
+def test_fit_block_n(width, block_n, want):
+    from repro.kernels.fish_count import fit_block_n
+
+    assert fit_block_n(width, block_n) == want
+
+
+@pytest.mark.parametrize("kernel", ("fish_count", "fish_epoch_count",
+                                    "store_probe"))
+def test_kernels_bit_identical_across_block_sizes(kernel):
+    """Every block size gives the same bits, and the oracle's values
+    (exactly, but for one rounding of the decayed counters, which XLA
+    may contract into a fused multiply-add in the oracle)."""
+    from repro.kernels.ref import fish_epoch_count_ref
+
+    rng = np.random.default_rng(11)
+    k = 2_048
+    table = np.sort(np.concatenate([
+        rng.choice(6_000, 1_500, replace=False),
+        np.full(k - 1_500, -1)])).astype(np.int32)
+    t = jnp.asarray(table)
+    c = jnp.asarray(rng.gamma(2.0, 3.0, k).astype(np.float32))
+    kk = jnp.asarray(rng.integers(0, 7_000, 1_000).astype(np.int32))
+    vv = jnp.asarray(rng.integers(-50, 50, 1_000).astype(np.int32))
+    run, oracle = {
+        "fish_count": (lambda b: ops.fish_count(t, kk, block_n=b),
+                       lambda: fish_count_ref(t, kk)),
+        "fish_epoch_count": (
+            lambda b: ops.fish_epoch_count(t, c, kk, alpha=0.3, block_n=b),
+            lambda: fish_epoch_count_ref(t, c, kk, alpha=0.3)),
+        "store_probe": (
+            lambda b: ops.store_probe(t, kk, vv, block_n=b, impl="pallas"),
+            lambda: ops.store_probe(t, kk, vv, impl="sorted")),
+    }[kernel]
+    base = [np.asarray(x) for x in run(8)]
+    for block_n in (None, 64, 1024):
+        for g, b in zip(run(block_n), base):
+            np.testing.assert_array_equal(np.asarray(g), b)
+    for i, (b, w) in enumerate(zip(base, oracle())):
+        if kernel == "fish_epoch_count" and i == 0:
+            np.testing.assert_allclose(b, np.asarray(w), rtol=2e-7)
+        else:
+            np.testing.assert_array_equal(b, np.asarray(w))
+
+
+# ---------------------------------------------------------------------------
 # SSD chunk kernels
 # ---------------------------------------------------------------------------
 

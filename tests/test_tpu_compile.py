@@ -1,0 +1,122 @@
+"""Ahead-of-time compiles for a TPU v5e chip that is described, not attached.
+
+The fused segment function at the chip smoke's shapes (129 worker lanes x
+2^20+1 key rows x 16,384 tuples, associative-scan FIFO) and the three
+stream Pallas kernels at K=4096 slots x N=16,384 keys.  What the chip's
+compiler refuses (scoped-VMEM overflow, a program past 16 GB) fails here
+at no chip time.  Nothing runs, so this says nothing about results.
+
+The topology is described inside a fixture, never at import: only one
+process at a time may load the TPU library, and every test worker imports
+this file.
+"""
+
+import os
+
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+from repro.kernels import feed_fused
+from repro.kernels import fish_count as _fish_count
+from repro.kernels import store_probe as _store_probe
+
+HBM_BYTES = 16 * 10 ** 9  # one v5e chip
+WORKERS = 128
+KEY_CAP = 1 << 20
+FEED = 16_384
+RING_POINTS = 64 * WORKERS  # 64 virtual nodes per worker (core/baselines)
+K_SLOTS, N_KEYS = 4_096, 16_384
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    from jax.sharding import SingleDeviceSharding
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler in this installation
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a described chip's executables cannot be read back from the
+    # persistent cache, so keep them out of it
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _segment_specs(spec, scheme, n_pad, w1, kcap1, r_n, dmax, reset):
+    """(dev, a) argument shapes of one fused segment launch, laid out as
+    :meth:`FusedEdgeRunner.run_segment` builds them (with a pane)."""
+    i32, f32, u32 = jnp.int32, jnp.float32, jnp.uint32
+    a = {"keys": spec((n_pad,), i32), "m": spec((), i32),
+         "t": spec((n_pad,), f32), "busy": spec((w1,), f32),
+         "caps": spec((w1,), f32), "counts": spec((w1,), i32),
+         "vals": spec((n_pad,), i32), "seg_base": spec((), i32)}
+    dev = {"repl": spec((kcap1, w1), jnp.bool_)}
+    if scheme == "sg":
+        a.update(act=spec((w1,), i32), a_live=spec((), i32),
+                 rr=spec((), i32))
+    else:
+        a.update(pts=spec((r_n,), u32), cands=spec((r_n, dmax), i32),
+                 h=spec((n_pad,), u32))
+    if scheme == "fish":
+        dev.update(trk=spec((kcap1,), f32), m_k=spec((kcap1,), i32))
+        a.update(theta=spec((), f32), wnum=spec((), f32),
+                 alpha=spec((), f32), epoch=spec((), i32),
+                 g0=spec((), i32), pre_decay=spec((), i32),
+                 c_total=spec((), f32), d_min=spec((), i32),
+                 ebl=spec((w1,), f32), eas=spec((w1,), f32),
+                 ecaps=spec((w1,), f32), do_tick=spec((), i32),
+                 elapsed=spec((), f32))
+    if not reset:
+        dev.update(pane_tab=spec((w1, kcap1, 2), i32),
+                   pane_last=spec((w1,), i32))
+    return dev, a
+
+
+@pytest.mark.parametrize("reset", (True, False), ids=("fresh", "continuing"))
+@pytest.mark.parametrize("scheme", ("sg", "pkg", "fish"))
+def test_segment_compiles_for_v5e(one_chip, scheme, reset):
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    w1, kcap1 = WORKERS + 1, KEY_CAP + 1
+    r_n = 0 if scheme == "sg" else RING_POINTS
+    dmax = {"sg": 0, "pkg": 2}.get(scheme, WORKERS)
+    sig = (scheme, FEED, w1, kcap1, r_n, dmax, True, reset, "assoc")
+    dev, a = _segment_specs(spec, *sig[:6], reset)
+    ma = feed_fused._get_seg_fn(sig).lower(dev, a).compile(
+    ).memory_analysis()
+    live = (ma.argument_size_in_bytes + ma.temp_size_in_bytes
+            + ma.output_size_in_bytes - ma.alias_size_in_bytes)
+    assert live < HBM_BYTES
+    # the per-key tables are donated: a continuing pane updates in place
+    if not reset:
+        assert ma.alias_size_in_bytes >= w1 * kcap1 * 2 * 4
+
+
+@pytest.mark.parametrize("kernel", ("store_probe", "fish_count",
+                                    "fish_epoch_count"))
+def test_stream_kernel_compiles_for_v5e(one_chip, kernel):
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    table = spec((K_SLOTS,), jnp.int32)
+    keys = spec((N_KEYS,), jnp.int32)
+    fn, args = {
+        "store_probe": (_store_probe.store_probe, (table, keys, keys)),
+        "fish_count": (_fish_count.fish_count, (table, keys)),
+        "fish_epoch_count": (
+            lambda t, c, k: _fish_count.fish_epoch_count(t, c, k, alpha=0.5),
+            (table, spec((K_SLOTS,), jnp.float32), keys)),
+    }[kernel]
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text
