@@ -297,27 +297,29 @@ def _route_dcwc(a, row, scheme):
     least-loaded selection (argmin tie -> first candidate in ring order,
     matching ``min(cl, key=counts.__getitem__)``; WC's full-set argmin
     tie -> smallest worker id, matching the (count, id) heap)."""
-    trk, f, _ = _tracker_update(a, scheme)
-    hot = f > a["theta"]
-    wnum = a["wnum"]  # live worker-universe size (traced; can grow mid-run)
-    d_heavy = jnp.clip(jnp.ceil(f * wnum / jnp.sqrt(a["theta"])),
-                       2.0, wnum).astype(jnp.int32)
-    d = jnp.where(hot, d_heavy, 2)
-    dmax = row.shape[1]
-    iota_d = jnp.arange(dmax, dtype=jnp.int32)
+    with jax.named_scope("route/tracker"):
+        trk, f, _ = _tracker_update(a, scheme)
+    with jax.named_scope("route/choose"):
+        hot = f > a["theta"]
+        wnum = a["wnum"]  # live worker-universe size (traced; can grow)
+        d_heavy = jnp.clip(jnp.ceil(f * wnum / jnp.sqrt(a["theta"])),
+                           2.0, wnum).astype(jnp.int32)
+        d = jnp.where(hot, d_heavy, 2)
+        dmax = row.shape[1]
+        iota_d = jnp.arange(dmax, dtype=jnp.int32)
 
-    def step(counts, x):
-        r, dd, h, v = x
-        waits = jnp.where((iota_d < dd) & (r >= 0), counts[r], _BIG_I32)
-        w = r[jnp.argmin(waits)]
-        if scheme == "wc":
-            full = jnp.where(a["act_mask"], counts, _BIG_I32)
-            w = jnp.where(h, jnp.argmin(full).astype(w.dtype), w)
-        w = jnp.where(v, w, a["phantom_w"])
-        return counts.at[w].add(v.astype(jnp.int32)), w
+        def step(counts, x):
+            r, dd, h, v = x
+            waits = jnp.where((iota_d < dd) & (r >= 0), counts[r], _BIG_I32)
+            w = r[jnp.argmin(waits)]
+            if scheme == "wc":
+                full = jnp.where(a["act_mask"], counts, _BIG_I32)
+                w = jnp.where(h, jnp.argmin(full).astype(w.dtype), w)
+            w = jnp.where(v, w, a["phantom_w"])
+            return counts.at[w].add(v.astype(jnp.int32)), w
 
-    counts, workers = jax.lax.scan(
-        step, a["counts"], (row, d, hot, a["valid"]))
+        counts, workers = jax.lax.scan(
+            step, a["counts"], (row, d, hot, a["valid"]))
     return counts, workers, trk
 
 
@@ -326,45 +328,47 @@ def _route_fish(a, row):
     memory M_k) + Alg. 3 (per-tuple Eq. 2 wait-time argmin against the
     estimator state) — the per-tuple oracle's selection with frequencies
     read once per FISH epoch."""
-    trk, f, f_top = _tracker_update(a, "fish")
-    hot = (f > a["theta"]) & (f > 0.0) & (f_top > 0.0)
-    ratio = jnp.maximum(f_top / jnp.maximum(f, 1e-30), 1.0)
-    index = jnp.clip(jnp.floor(jnp.log2(ratio)), 0.0, 30.0)
-    wnum = a["wnum"]
-    d0 = jnp.clip(jnp.floor(wnum / jnp.exp2(index)),
-                  a["d_min"].astype(jnp.float32), wnum).astype(jnp.int32)
-    m_prev = a["m_k"][a["keys"]]
-    d = jnp.where(hot, jnp.maximum(d0, m_prev), 2)
-    m_k = a["m_k"].at[a["keys"]].max(
-        jnp.where(hot & a["valid"], jnp.maximum(m_prev, d0), 0))
+    with jax.named_scope("route/tracker"):
+        trk, f, f_top = _tracker_update(a, "fish")
+    with jax.named_scope("route/choose"):
+        hot = (f > a["theta"]) & (f > 0.0) & (f_top > 0.0)
+        ratio = jnp.maximum(f_top / jnp.maximum(f, 1e-30), 1.0)
+        index = jnp.clip(jnp.floor(jnp.log2(ratio)), 0.0, 30.0)
+        wnum = a["wnum"]
+        d0 = jnp.clip(jnp.floor(wnum / jnp.exp2(index)),
+                      a["d_min"].astype(jnp.float32), wnum).astype(jnp.int32)
+        m_prev = a["m_k"][a["keys"]]
+        d = jnp.where(hot, jnp.maximum(d0, m_prev), 2)
+        m_k = a["m_k"].at[a["keys"]].max(
+            jnp.where(hot & a["valid"], jnp.maximum(m_prev, d0), 0))
 
-    # estimator tick (Alg. 3 Eq. 1), applied once at segment start when due
-    backlog, assigned = a["ebl"], a["eas"]
-    work = (backlog + assigned) * a["ecaps"]
-    ticked = jnp.where(work > a["elapsed"],
-                       (work - a["elapsed"]) / a["ecaps"], 0.0)
-    backlog = jnp.where(a["do_tick"] > 0, ticked, backlog)
-    assigned = jnp.where(a["do_tick"] > 0, 0.0, assigned)
+        # estimator tick (Alg. 3 Eq. 1), applied once at segment start when due
+        backlog, assigned = a["ebl"], a["eas"]
+        work = (backlog + assigned) * a["ecaps"]
+        ticked = jnp.where(work > a["elapsed"],
+                           (work - a["elapsed"]) / a["ecaps"], 0.0)
+        backlog = jnp.where(a["do_tick"] > 0, ticked, backlog)
+        assigned = jnp.where(a["do_tick"] > 0, 0.0, assigned)
 
-    dmax = row.shape[1]
-    iota_d = jnp.arange(dmax, dtype=jnp.int32)
-    # the scan reads only `asn`; counts never feed the argmin, so they
-    # accumulate in one dense pass after the loop instead of a scatter
-    # per step.
-    def step(asn, x):
-        r, dd, v = x
-        waits = jnp.where((iota_d < dd) & (r >= 0),
-                          (backlog[r] + asn[r]) * a["ecaps"][r], jnp.inf)
-        w = r[jnp.argmin(waits)]
-        w = jnp.where(v, w, a["phantom_w"])
-        return asn.at[w].add(jnp.where(v, 1.0, 0.0)), w
+        dmax = row.shape[1]
+        iota_d = jnp.arange(dmax, dtype=jnp.int32)
+        # the scan reads only `asn`; counts never feed the argmin, so they
+        # accumulate in one dense pass after the loop instead of a scatter
+        # per step.
+        def step(asn, x):
+            r, dd, v = x
+            waits = jnp.where((iota_d < dd) & (r >= 0),
+                              (backlog[r] + asn[r]) * a["ecaps"][r], jnp.inf)
+            w = r[jnp.argmin(waits)]
+            w = jnp.where(v, w, a["phantom_w"])
+            return asn.at[w].add(jnp.where(v, 1.0, 0.0)), w
 
-    assigned, workers = jax.lax.scan(
-        step, assigned, (row, d, a["valid"]))
-    lanes = jnp.arange(a["counts"].shape[0], dtype=workers.dtype)
-    counts = a["counts"] + jnp.sum(
-        (workers[None, :] == lanes[:, None]) & a["valid"][None, :],
-        axis=1).astype(jnp.int32)
+        assigned, workers = jax.lax.scan(
+            step, assigned, (row, d, a["valid"]))
+        lanes = jnp.arange(a["counts"].shape[0], dtype=workers.dtype)
+        counts = a["counts"] + jnp.sum(
+            (workers[None, :] == lanes[:, None]) & a["valid"][None, :],
+            axis=1).astype(jnp.int32)
     return counts, workers, trk, m_k, backlog, assigned
 
 
@@ -372,7 +376,14 @@ def _get_seg_fn(sig):
     """Build (or fetch) the jitted segment function for one static shape
     signature — (scheme, padded length, worker lanes, key rows, ring
     points, candidate width, pane?, fresh pane?, fifo impl) is the
-    recompile boundary."""
+    recompile boundary.
+
+    Each phase runs under a ``jax.named_scope`` (``route/ring``,
+    ``route/tracker``, ``route/choose``, ``fifo``, ``pane/scatter``,
+    ``pane/count_plane``, ``pane/replicas``, ``pane/last``, or
+    ``replicas`` without a pane), which names its operations in a device
+    trace's ``tf_op`` metadata; the compiled program is the same.  The
+    module is ``jit_seg_<scheme>``."""
     fn = _SEG_CACHE.get(sig)
     if fn is not None:
         return fn
@@ -401,18 +412,23 @@ def _get_seg_fn(sig):
                    & a["valid"][None, :]).sum(axis=1, dtype=jnp.int32)
             return a["counts"] + seg
 
+        # DC/WC/FISH scope their tracker and choice phases themselves
         if scheme == "sg":
-            iota = jnp.arange(n_pad, dtype=jnp.int32)
-            workers = a["act"][(a["rr"] + iota) % a["a_live"]]
-            workers = jnp.where(a["valid"], workers, phantom_w)
-            counts = _count(workers)
-        else:
-            row = _ring_rows(a, {"fg": 1, "pkg": 2}.get(scheme))
-            if scheme == "fg":
-                workers = jnp.where(a["valid"], row[:, 0], phantom_w)
+            with jax.named_scope("route/choose"):
+                iota = jnp.arange(n_pad, dtype=jnp.int32)
+                workers = a["act"][(a["rr"] + iota) % a["a_live"]]
+                workers = jnp.where(a["valid"], workers, phantom_w)
                 counts = _count(workers)
+        else:
+            with jax.named_scope("route/ring"):
+                row = _ring_rows(a, {"fg": 1, "pkg": 2}.get(scheme))
+            if scheme == "fg":
+                with jax.named_scope("route/choose"):
+                    workers = jnp.where(a["valid"], row[:, 0], phantom_w)
+                    counts = _count(workers)
             elif scheme == "pkg":
-                counts, workers = _route_pkg(a, row)
+                with jax.named_scope("route/choose"):
+                    counts, workers = _route_pkg(a, row)
             elif scheme in ("dc", "wc"):
                 counts, workers, trk = _route_dcwc(a, row, scheme)
             else:  # fish
@@ -424,49 +440,59 @@ def _get_seg_fn(sig):
         if trk is not None:
             out["trk"] = trk
 
-        busy, fin = fifo(a["busy"], a["caps"], workers, a["t"])
+        with jax.named_scope("fifo"):
+            busy, fin = fifo(a["busy"], a["caps"], workers, a["t"])
         out["fin"] = fin
         out["busy"] = busy
         out["counts"] = counts
         if has_pane:
-            # one stacked scatter updates value and count planes together,
-            # through a flat row index (1-D indexed scatters lower to a
-            # cheaper XLA scatter than 2-D ones on CPU); its count plane
-            # then gives the replica update as a dense OR — both measurably
-            # cheaper than separate 2-D scatters
-            vc = jnp.stack([jnp.where(a["valid"], a["vals"], 0),
-                            a["valid"].astype(jnp.int32)], axis=-1)
-            # worker-major flat index: the host flush's flatnonzero then
-            # yields entries already grouped per worker with keys
-            # ascending, so it needs no sort at all
-            flat = workers * kcap1 + a["keys"]
-            # `reset` marks the first segment of a pane: the tables start
-            # from in-jit zeros (a fused memset) instead of round-tripping
-            # an eagerly allocated zero buffer through the launch
-            base = (jnp.zeros((w1 * kcap1, 2), jnp.int32) if reset
-                    else a["pane_tab"].reshape(w1 * kcap1, 2))
-            # indices are in-bounds by construction (the phantom worker
-            # lane and phantom key row absorb padding), so skipping the
-            # per-element bounds check measurably speeds the CPU scatter
-            pane = base.at[flat].add(
-                vc, mode="promise_in_bounds").reshape(w1, kcap1, 2)
+            with jax.named_scope("pane/scatter"):
+                # one stacked scatter updates value and count planes
+                # together, through a flat row index (1-D indexed scatters
+                # lower to a cheaper XLA scatter than 2-D ones on CPU); its
+                # count plane then gives the replica update as a dense OR
+                # — both measurably cheaper than separate 2-D scatters
+                vc = jnp.stack([jnp.where(a["valid"], a["vals"], 0),
+                                a["valid"].astype(jnp.int32)], axis=-1)
+                # worker-major flat index: the host flush's flatnonzero
+                # then yields entries already grouped per worker with keys
+                # ascending, so it needs no sort at all
+                flat = workers * kcap1 + a["keys"]
+                # `reset` marks the first segment of a pane: the tables
+                # start from in-jit zeros (a fused memset) instead of
+                # round-tripping an eagerly allocated zero buffer through
+                # the launch
+                base = (jnp.zeros((w1 * kcap1, 2), jnp.int32) if reset
+                        else a["pane_tab"].reshape(w1 * kcap1, 2))
+                # indices are in-bounds by construction (the phantom
+                # worker lane and phantom key row absorb padding), so
+                # skipping the per-element bounds check measurably speeds
+                # the CPU scatter
+                pane = base.at[flat].add(
+                    vc, mode="promise_in_bounds").reshape(w1, kcap1, 2)
             out["pane_tab"] = pane
             # contiguous count-plane copy: the host flush scans this with
             # one flatnonzero instead of a strided nonzero over the table
-            out["pane_cnt"] = pane[:, :, 1]
-            out["repl"] = a["repl"] | (pane[:, :, 1] > 0).T
-            gidx = a["seg_base"] + jnp.arange(n_pad, dtype=jnp.int32)
-            gidx = jnp.where(a["valid"], gidx, -1)
-            lanes = jnp.arange(w1, dtype=jnp.int32)
-            seg_last = jnp.max(
-                jnp.where(workers[None, :] == lanes[:, None],
-                          gidx[None, :], -1), axis=1)
-            out["pane_last"] = (seg_last if reset else
-                                jnp.maximum(a["pane_last"], seg_last))
+            with jax.named_scope("pane/count_plane"):
+                out["pane_cnt"] = pane[:, :, 1]
+            with jax.named_scope("pane/replicas"):
+                out["repl"] = a["repl"] | (pane[:, :, 1] > 0).T
+            with jax.named_scope("pane/last"):
+                gidx = a["seg_base"] + jnp.arange(n_pad, dtype=jnp.int32)
+                gidx = jnp.where(a["valid"], gidx, -1)
+                lanes = jnp.arange(w1, dtype=jnp.int32)
+                seg_last = jnp.max(
+                    jnp.where(workers[None, :] == lanes[:, None],
+                              gidx[None, :], -1), axis=1)
+                out["pane_last"] = (seg_last if reset else
+                                    jnp.maximum(a["pane_last"], seg_last))
         else:
-            out["repl"] = a["repl"].at[a["keys"], workers].set(True)
+            with jax.named_scope("replicas"):
+                out["repl"] = a["repl"].at[a["keys"], workers].set(True)
         return out
 
+    # the per-scheme name makes the module `jit_seg_<scheme>`
+    seg.__name__ = seg.__qualname__ = f"seg_{scheme}"
     fn = _SEG_CACHE[sig] = jax.jit(seg, donate_argnums=0)
     return fn
 
@@ -715,11 +741,12 @@ class FusedEdgeRunner:
                self.fifo_impl)
         prep_span.done()
         # the one device dispatch: routing, FIFO and state-scatter run as
-        # a single fused launch, so the phases share this span (the
-        # ``phases`` arg names them for the Perfetto detail pane — see
-        # DESIGN.md §14 on why they cannot be timed separately)
-        with tracer.span("fused.segment.launch", cat="fused", n_pad=n_pad,
-                         phases="route|fifo|state-scatter"):
+        # a single fused launch, so the phases share this span; the device
+        # trace times them apart by their named scopes (DESIGN.md §14)
+        with tracer.span("fused.segment.launch", cat="fused",
+                         n_pad=n_pad) as launch_span:
+            if sig not in _SEG_CACHE:
+                launch_span.set(new_signature=True)  # this launch compiles
             out = _get_seg_fn(sig)(dev, a)
         self._c_dispatches.add(1)
 
@@ -754,7 +781,9 @@ class FusedEdgeRunner:
             fin = self._base + np.asarray(out["fin"], dtype=np.float64)[:m]
         if (scheme == "fish" and self.tel.enabled
                 and self._fish_epochs_crossed):
-            self._fish_epoch_points(grouper, state, lo, hi)
+            # the tracker read is a cost of tracing: its own span shows it
+            with tracer.span("fish.epoch_points", cat="fish"):
+                self._fish_epoch_points(grouper, state, lo, hi)
         seg_span.done()
         return fin
 
@@ -833,17 +862,23 @@ class FusedEdgeRunner:
     def flush_pane(self, sink) -> None:
         """Sync the open device pane into the host KeyedStateManager and
         drop the device tables (``merge_entries`` accumulates, so the pane
-        can keep filling on device afterwards)."""
+        can keep filling on device afterwards).
+
+        Its three steps are child spans of ``fused.pane_flush``: one
+        ``.copy`` per plane, the ``.scan`` for live entries and the
+        ``.merge`` into the host store."""
         if not self.has_pane or self.pane_fed == 0:
             return
         self._c_pane_flushes.add(1)
-        flush_span = self.tel.tracer.span("fused.pane_flush", cat="fused",
-                                          pane_fed=self.pane_fed)
+        tracer = self.tel.tracer
+        flush_span = tracer.span("fused.pane_flush", cat="fused",
+                                 pane_fed=self.pane_fed)
         # device-to-host copies of the whole count and value planes
         # (w1 x kcap1 each), however few entries are live
-        cnt = np.asarray(self.pane_cnt)
-        tab = np.asarray(self.pane_tab).reshape(-1, 2)
-        last = np.asarray(self.pane_last)
+        cnt = self._to_host("pane_cnt", self.pane_cnt)
+        tab = self._to_host("pane_tab", self.pane_tab, (-1, 2))
+        last = self._to_host("pane_last", self.pane_last)
+        scan_span = tracer.span("fused.pane_flush.scan", cat="fused")
         # phantom row/lane never accumulate (padding lanes scatter zeros),
         # so one flatnonzero over the contiguous count plane finds every
         # live entry — already per-worker grouped with keys ascending,
@@ -860,7 +895,11 @@ class FusedEdgeRunner:
             for s, e in zip(starts[:-1].tolist(), starts[1:].tolist()):
                 w = int(ws[s])
                 entries.append((w, ks[s:e], vs[s:e], cs[s:e], int(last[w])))
-        sink.feed_aggregated(self.pane_fed, entries)
+        live = int(flat.shape[0])
+        scan_span.set(live=live).done()
+        with tracer.span("fused.pane_flush.merge", cat="fused",
+                         entries=live):
+            sink.feed_aggregated(self.pane_fed, entries)
         # None marks the pane empty — the next segment's launch starts
         # from in-jit zeros (its `reset` variant), so no buffer is
         # allocated or transferred here
@@ -869,6 +908,19 @@ class FusedEdgeRunner:
         self.pane_last = None
         self.pane_fed = 0
         flush_span.done()
+
+    def _to_host(self, name: str, arr, shape=None) -> np.ndarray:
+        """One pane plane copied device to host (and reshaped to ``shape``),
+        as a ``fused.pane_flush.copy`` span with the host array's bytes.
+        The reshape belongs to the copy: on a TPU the host array keeps
+        the device's dimension order, so reshaping it copies again."""
+        with self.tel.tracer.span("fused.pane_flush.copy", cat="fused",
+                                  array=name) as span:
+            out = np.asarray(arr)
+            if shape is not None:
+                out = out.reshape(shape)
+            span.set(bytes=out.nbytes)
+        return out
 
     def host_sync(self, grouper) -> None:
         """Fold device-resident per-key state back into the grouper: new

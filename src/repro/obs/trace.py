@@ -3,7 +3,10 @@
 Spans are recorded against a monotonic clock (``time.perf_counter``)
 anchored to one wall-clock instant at tracer construction, so exported
 Chrome-trace timestamps are drift-free within a run and still carry an
-absolute ``trace_start_wall`` in metadata.  The disabled path is a pair of
+absolute ``trace_start_wall`` in metadata.  Each enabled span also holds a
+``jax.profiler.TraceAnnotation`` of its name from open to ``done()``, so a
+profile taken around the session shows the spans in its host plane, on
+the device events' clock.  The disabled path is a pair of
 shared singletons (:data:`NULL_TRACER` handing out :data:`NULL_SPAN`):
 no allocation, no clock read, no list append — the overhead contract in
 DESIGN.md §14.
@@ -19,9 +22,13 @@ __all__ = ["Span", "Tracer", "NullTracer", "NULL_TRACER", "NULL_SPAN"]
 
 class Span:
     """One traced interval.  Used as a context manager; ``set(**kw)``
-    attaches args visible in the Perfetto detail pane."""
+    attaches args visible in the Perfetto detail pane.
 
-    __slots__ = ("name", "cat", "t0", "t1", "args", "_tracer")
+    The span owns its profiler annotation: ``done()`` closes it, and a
+    span dropped without ``done()`` closes it as it is freed, so no
+    annotation outlives its span."""
+
+    __slots__ = ("name", "cat", "t0", "t1", "args", "_tracer", "_ann")
 
     def __init__(self, tracer: "Tracer", name: str, cat: str,
                  args: Optional[Dict]):
@@ -29,6 +36,8 @@ class Span:
         self.name = name
         self.cat = cat
         self.args = args
+        self._ann = tracer.annotation(name)
+        self._ann.__enter__()
         self.t0 = time.perf_counter()
         self.t1 = -1.0
 
@@ -42,6 +51,8 @@ class Span:
     def done(self) -> None:
         if self.t1 < 0.0:
             self.t1 = time.perf_counter()
+            self._ann.__exit__(None, None, None)
+            self._ann = None
             self._tracer.spans.append(self)
 
     def __enter__(self) -> "Span":
@@ -56,6 +67,10 @@ class Tracer:
     """Collects :class:`Span`s and instant events in memory."""
 
     def __init__(self) -> None:
+        # imported here: only an enabled tracer touches the profiler
+        from jax.profiler import TraceAnnotation
+
+        self.annotation = TraceAnnotation
         self.t0 = time.perf_counter()
         self.wall0 = time.time()
         self.spans: List[Span] = []

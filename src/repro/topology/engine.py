@@ -592,6 +592,13 @@ def _state_extra(srep: Optional[StateReport]) -> Dict:
                 tuples_replayed=srep.tuples_replayed)
 
 
+def _emit_args(span, partials, emission) -> None:
+    """``session.emit``'s args: the window partials released and the
+    partial-aggregate tuples they carry downstream."""
+    span.set(partials=len(partials),
+             entries=0 if emission is None else len(emission[0]))
+
+
 def _emit_partials(partials, finishes: np.ndarray, in_roots: np.ndarray,
                    fallback_time: float):
     """The stream a batch of flushed window partials emits downstream: one
@@ -816,13 +823,17 @@ class SimulatorSession(_BaseSession):
                 if st.stage.name not in self._sinks:
                     rest = st.mgr.partials[st.emitted:]
                     if rest or st.emitted == 0:
-                        fin = (np.concatenate(st.finishes) if st.finishes
-                               else np.empty(0))
-                        roots = (np.concatenate(st.roots) if st.roots
-                                 else np.empty(0, dtype=np.int64))
-                        streams[st.stage.name] = _emit_partials(
-                            rest, fin, roots,
-                            float(fin.max()) if fin.size else 0.0)
+                        with self.telemetry.tracer.span(
+                                "session.emit", cat="session") as emit_span:
+                            fin = (np.concatenate(st.finishes)
+                                   if st.finishes else np.empty(0))
+                            roots = (np.concatenate(st.roots) if st.roots
+                                     else np.empty(0, dtype=np.int64))
+                            emission = _emit_partials(
+                                rest, fin, roots,
+                                float(fin.max()) if fin.size else 0.0)
+                            _emit_args(emit_span, rest, emission)
+                        streams[st.stage.name] = emission
                         st.emitted = len(st.mgr.partials)
 
     def _run_edge(self, edge: Edge, in_keys, in_times, in_roots, in_values,
@@ -914,14 +925,20 @@ class SimulatorSession(_BaseSession):
             # operator stages flush closed windows downstream at the end of
             # each feed (incremental emission — ISSUE 6); the remainder goes
             # out at close().  Finish times anchor the partial stream.
-            st.finishes.append(res.finishes)
-            st.roots.append(np.asarray(in_roots))
-            fresh = mgr.drain_partials(st.emitted)
-            if fresh:
-                st.emitted += len(fresh)
-                fin = np.concatenate(st.finishes)
-                roots = np.concatenate(st.roots)
-                return _emit_partials(fresh, fin, roots, float(fin.max()))
+            with self.telemetry.tracer.span("session.emit",
+                                            cat="session") as emit_span:
+                st.finishes.append(res.finishes)
+                st.roots.append(np.asarray(in_roots))
+                fresh = mgr.drain_partials(st.emitted)
+                emission = None
+                if fresh:
+                    st.emitted += len(fresh)
+                    fin = np.concatenate(st.finishes)
+                    roots = np.concatenate(st.roots)
+                    emission = _emit_partials(fresh, fin, roots,
+                                              float(fin.max()))
+                _emit_args(emit_span, fresh, emission)
+            return emission
         else:  # intermediate stage: release transformed tuples
             return _emit(stage, in_keys, res.finishes, in_roots, in_values)
         return None
@@ -1224,11 +1241,15 @@ class ServingSession(_BaseSession):
                 if st.stage.name not in self._sinks:
                     rest = st.mgr.partials[st.emitted:]
                     if rest or st.emitted == 0:
-                        fins = np.array([r.finished for r in st.reqs])
-                        roots = (np.concatenate(st.roots) if st.roots
-                                 else np.empty(0, dtype=np.int64))
-                        streams[st.stage.name] = _emit_partials(
-                            rest, fins, roots, float(st.eng.now))
+                        with self.telemetry.tracer.span(
+                                "session.emit", cat="session") as emit_span:
+                            fins = np.array([r.finished for r in st.reqs])
+                            roots = (np.concatenate(st.roots) if st.roots
+                                     else np.empty(0, dtype=np.int64))
+                            emission = _emit_partials(
+                                rest, fins, roots, float(st.eng.now))
+                            _emit_args(emit_span, rest, emission)
+                        streams[st.stage.name] = emission
                         st.emitted = len(st.mgr.partials)
 
     def _run_edge(self, edge: Edge, in_keys, in_times, in_roots,
@@ -1346,13 +1367,18 @@ class ServingSession(_BaseSession):
         elif mgr is not None:
             # windows that closed during this feed go downstream now; the
             # remainder is released at close() (incremental emission)
-            fresh = mgr.drain_partials(st.emitted)
-            if fresh:
-                st.emitted += len(fresh)
-                all_fins = np.array([r.finished for r in st.reqs])
-                roots = np.concatenate(st.roots)
-                return _emit_partials(fresh, all_fins, roots,
-                                      float(st.eng.now))
+            with self.telemetry.tracer.span("session.emit",
+                                            cat="session") as emit_span:
+                fresh = mgr.drain_partials(st.emitted)
+                emission = None
+                if fresh:
+                    st.emitted += len(fresh)
+                    all_fins = np.array([r.finished for r in st.reqs])
+                    roots = np.concatenate(st.roots)
+                    emission = _emit_partials(fresh, all_fins, roots,
+                                              float(st.eng.now))
+                _emit_args(emit_span, fresh, emission)
+            return emission
         else:  # intermediate stage: release transformed tuples
             return _emit(stage, in_keys[done], finishes[done],
                          in_roots[done],
