@@ -141,7 +141,7 @@ def topology(scheme: str, workers: int, merge_workers: int, window: int):
 
 
 def _device_tables(runner):
-    names = ("repl", "trk", "m_k", "pane_tab", "pane_cnt", "pane_last")
+    names = ("repl", "trk", "m_k", "pane_tab", "pane_last")
     return {n: getattr(runner, n) for n in names
             if getattr(runner, n) is not None}
 

@@ -29,7 +29,8 @@ dispatch (counted in :attr:`FusedEdgeRunner.dispatches`, surfaced as
 ``EdgeResult.dispatches``): per-key state (tracker, CHK memory, replica
 matrix, pane tables) stays device-resident across feeds; only the small
 per-worker vectors (busy, counts, estimator) and the per-tuple finish
-times cross the boundary as part of the launch round-trip.
+times (and, with a pane, workers) cross the boundary as part of the
+launch round-trip.
 
 Shape discipline: segment lengths pad to power-of-two buckets (min
 :data:`MIN_BUCKET`) so varying RecordBatch lengths reuse one trace;
@@ -380,7 +381,7 @@ def _get_seg_fn(sig):
 
     Each phase runs under a ``jax.named_scope`` (``route/ring``,
     ``route/tracker``, ``route/choose``, ``fifo``, ``pane/scatter``,
-    ``pane/count_plane``, ``pane/replicas``, ``pane/last``, or
+    ``pane/replicas``, ``pane/last``, or
     ``replicas`` without a pane), which names its operations in a device
     trace's ``tf_op`` metadata; the compiled program is the same.  The
     module is ``jit_seg_<scheme>``."""
@@ -454,9 +455,7 @@ def _get_seg_fn(sig):
                 # — both measurably cheaper than separate 2-D scatters
                 vc = jnp.stack([jnp.where(a["valid"], a["vals"], 0),
                                 a["valid"].astype(jnp.int32)], axis=-1)
-                # worker-major flat index: the host flush's flatnonzero
-                # then yields entries already grouped per worker with keys
-                # ascending, so it needs no sort at all
+                # worker-major flat index into the (w1, kcap1) planes
                 flat = workers * kcap1 + a["keys"]
                 # `reset` marks the first segment of a pane: the tables
                 # start from in-jit zeros (a fused memset) instead of
@@ -471,10 +470,10 @@ def _get_seg_fn(sig):
                 pane = base.at[flat].add(
                     vc, mode="promise_in_bounds").reshape(w1, kcap1, 2)
             out["pane_tab"] = pane
-            # contiguous count-plane copy: the host flush scans this with
-            # one flatnonzero instead of a strided nonzero over the table
-            with jax.named_scope("pane/count_plane"):
-                out["pane_cnt"] = pane[:, :, 1]
+            # each tuple's worker: with the host's keys it names every
+            # (worker, key) entry the pane touched, so the flush gathers
+            # those alone instead of reading the dense planes
+            out["workers"] = workers
             with jax.named_scope("pane/replicas"):
                 out["repl"] = a["repl"] | (pane[:, :, 1] > 0).T
             with jax.named_scope("pane/last"):
@@ -495,6 +494,18 @@ def _get_seg_fn(sig):
     seg.__name__ = seg.__qualname__ = f"seg_{scheme}"
     fn = _SEG_CACHE[sig] = jax.jit(seg, donate_argnums=0)
     return fn
+
+
+def pane_gather(tab, ws, ks):
+    """The (value, count) entries of the (w1, kcap1, 2) pane table at the
+    (worker, key) pairs ``(ws[i], ks[i])``, one row each.  The 2-D index
+    reads the table in its own layout, never reshaped; padding pairs name
+    the phantom lane and key row, which only ever hold zeros.  The module
+    is ``jit_pane_gather``, outside the ``jit_seg`` programs."""
+    return tab.at[ws, ks].get(mode="promise_in_bounds")
+
+
+_pane_gather = jax.jit(pane_gather)
 
 
 # ---------------------------------------------------------------------------
@@ -529,11 +540,16 @@ class FusedEdgeRunner:
             "fused.pane_flushes", scheme=self.scheme)
         self._c_host_syncs = self.tel.metrics.counter(
             "fused.host_syncs", scheme=self.scheme)
+        self._c_pane_gathers = self.tel.metrics.counter(
+            "fused.pane_gathers", scheme=self.scheme)
         self._feed_base_dispatches = 0
         self._prev_hot: set = set()   # fish hot set at the last epoch point
         self._fish_epoch_idx = -1
         self._fish_epochs_crossed = 0
         self.pane_fed = 0         # tuples in the device pane, unsynced
+        # (workers, keys) of the open pane's valid tuples, one pair of
+        # arrays per launch: the (w, k) entries the flush gathers
+        self._pane_pairs: list = []
         self._kcap = 0
         self._w1 = 0
         self._dmax = 1 if self.scheme == "fg" else (
@@ -550,7 +566,6 @@ class FusedEdgeRunner:
         self.m_k = None
         self.repl = None
         self.pane_tab = None      # (w1, kcap1, 2): value / count planes
-        self.pane_cnt = None      # contiguous count plane for the flush scan
         self.pane_last = None
         self._repl_synced = None  # host mirror of already-synced pairs
 
@@ -591,8 +606,6 @@ class FusedEdgeRunner:
             # `reset` variant rebuilds it at the new shape from zeros
             self.pane_tab = _grow_dev3(self.pane_tab, old_k, old_w,
                                        kcap1, w1)
-            self.pane_cnt = _grow_dev2(self.pane_cnt, old_w, old_k,
-                                       w1, kcap1, jnp.int32)
             self.pane_last = _grow_last(self.pane_last, old_w, w1)
         grew_w = w1 != self._w1
         self._kcap = new_kcap
@@ -758,7 +771,6 @@ class FusedEdgeRunner:
             self.m_k = out["m_k"]
         if self.has_pane:
             self.pane_tab = out["pane_tab"]
-            self.pane_cnt = out["pane_cnt"]
             self.pane_last = out["pane_last"]
             self.pane_fed += m
         self._repl_dirty = True
@@ -779,6 +791,9 @@ class FusedEdgeRunner:
                 est.assigned[:] = np.asarray(out["eas"],
                                              dtype=np.float64)[:nw]
             fin = self._base + np.asarray(out["fin"], dtype=np.float64)[:m]
+            if self.has_pane:
+                self._pane_pairs.append((np.asarray(out["workers"])[:m],
+                                         self._feed_keys[lo:hi]))
         if (scheme == "fish" and self.tel.enabled
                 and self._fish_epochs_crossed):
             # the tracker read is a cost of tracing: its own span shows it
@@ -864,63 +879,63 @@ class FusedEdgeRunner:
         drop the device tables (``merge_entries`` accumulates, so the pane
         can keep filling on device afterwards).
 
-        Its three steps are child spans of ``fused.pane_flush``: one
-        ``.copy`` per plane, the ``.scan`` for live entries and the
-        ``.merge`` into the host store."""
+        Every valid tuple adds 1 to the count of exactly one (worker, key)
+        entry, so the pane's live entries are the distinct pairs its
+        launches recorded: the flush gathers those alone from the device
+        table, at a cost that follows the tuples in the pane, not the
+        table's size.  Its three steps are child spans of
+        ``fused.pane_flush``: the ``.scan`` that dedupes and splits the
+        pairs per worker, the ``.copy`` that gathers the entries on the
+        device and fetches them, and the ``.merge`` into the host store."""
         if not self.has_pane or self.pane_fed == 0:
             return
         self._c_pane_flushes.add(1)
         tracer = self.tel.tracer
         flush_span = tracer.span("fused.pane_flush", cat="fused",
                                  pane_fed=self.pane_fed)
-        # device-to-host copies of the whole count and value planes
-        # (w1 x kcap1 each), however few entries are live
-        cnt = self._to_host("pane_cnt", self.pane_cnt)
-        tab = self._to_host("pane_tab", self.pane_tab, (-1, 2))
-        last = self._to_host("pane_last", self.pane_last)
         scan_span = tracer.span("fused.pane_flush.scan", cat="fused")
-        # phantom row/lane never accumulate (padding lanes scatter zeros),
-        # so one flatnonzero over the contiguous count plane finds every
-        # live entry — already per-worker grouped with keys ascending,
-        # because the device table is worker-major
-        flat = np.flatnonzero(cnt)
-        entries = []
-        if flat.shape[0]:
-            ws, ks0 = np.divmod(flat, cnt.shape[1])
-            ks = ks0.astype(np.int64)
-            vs = tab[flat, 0].astype(np.int64)
-            cs = tab[flat, 1].astype(np.int64)
-            starts = np.concatenate(
-                [[0], np.flatnonzero(ws[1:] != ws[:-1]) + 1, [ws.shape[0]]])
-            for s, e in zip(starts[:-1].tolist(), starts[1:].tolist()):
-                w = int(ws[s])
-                entries.append((w, ks[s:e], vs[s:e], cs[s:e], int(last[w])))
+        kcap1 = self._kcap + 1
+        ws_all = np.concatenate([w for w, _ in self._pane_pairs])
+        ks_all = np.concatenate([k for _, k in self._pane_pairs])
+        # pairs, not flat indices, are recorded: key-capacity growth
+        # mid-pane changes kcap1.  np.unique sorts worker-major with keys
+        # ascending, the order the manager's consumers rely on
+        flat = np.unique(ws_all.astype(np.int64) * kcap1 + ks_all)
+        ws, ks = np.divmod(flat, kcap1)
         live = int(flat.shape[0])
-        scan_span.set(live=live).done()
+        # one static bucket per pane size; the phantom lane and key row pad
+        bucket = _bucket(self.pane_fed)
+        ws_pad = np.full(bucket, self._w1 - 1, np.int32)
+        ws_pad[:live] = ws
+        ks_pad = np.full(bucket, self._kcap, np.int32)
+        ks_pad[:live] = ks
+        starts = np.concatenate(
+            [[0], np.flatnonzero(ws[1:] != ws[:-1]) + 1, [live]]).tolist()
+        runs = list(zip(starts[:-1], starts[1:]))  # one run per worker
+        scan_span.set(touched=int(ws_all.shape[0]), live=live).done()
+        with tracer.span("fused.pane_flush.copy", cat="fused",
+                         array="entries") as copy_span:
+            got, last = jax.device_get(
+                (_pane_gather(self.pane_tab, ws_pad, ks_pad),
+                 self.pane_last))
+            self._c_pane_gathers.add(1)
+            copy_span.set(bytes=got.nbytes + last.nbytes)
         with tracer.span("fused.pane_flush.merge", cat="fused",
                          entries=live):
-            sink.feed_aggregated(self.pane_fed, entries)
+            vs = got[:live, 0].astype(np.int64)
+            cs = got[:live, 1].astype(np.int64)
+            sink.feed_aggregated(
+                self.pane_fed,
+                [(int(ws[s]), ks[s:e], vs[s:e], cs[s:e], int(last[ws[s]]))
+                 for s, e in runs])
         # None marks the pane empty — the next segment's launch starts
         # from in-jit zeros (its `reset` variant), so no buffer is
         # allocated or transferred here
         self.pane_tab = None
-        self.pane_cnt = None
         self.pane_last = None
         self.pane_fed = 0
+        self._pane_pairs = []
         flush_span.done()
-
-    def _to_host(self, name: str, arr, shape=None) -> np.ndarray:
-        """One pane plane copied device to host (and reshaped to ``shape``),
-        as a ``fused.pane_flush.copy`` span with the host array's bytes.
-        The reshape belongs to the copy: on a TPU the host array keeps
-        the device's dimension order, so reshaping it copies again."""
-        with self.tel.tracer.span("fused.pane_flush.copy", cat="fused",
-                                  array=name) as span:
-            out = np.asarray(arr)
-            if shape is not None:
-                out = out.reshape(shape)
-            span.set(bytes=out.nbytes)
-        return out
 
     def host_sync(self, grouper) -> None:
         """Fold device-resident per-key state back into the grouper: new

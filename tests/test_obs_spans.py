@@ -72,8 +72,8 @@ def _scopes(scheme, has_pane):
              "fg": ["route/ring", "route/choose"],
              "pkg": ["route/ring", "route/choose"]}.get(
         scheme, ["route/ring", "route/tracker", "route/choose"])
-    tail = (["pane/scatter", "pane/count_plane", "pane/replicas",
-             "pane/last"] if has_pane else ["replicas"])
+    tail = (["pane/scatter", "pane/replicas", "pane/last"] if has_pane
+            else ["replicas"])
     return route + ["fifo"] + tail
 
 
@@ -114,22 +114,30 @@ def test_segment_scopes_in_compiled_hlo(scheme, monkeypatch):
 
 @pytest.fixture(scope="module")
 def fish_run():
-    """A traced FISH session's spans, and the bytes of the device planes
-    each pane flush found, in flush order."""
-    planes = []
-    real = feed_fused.FusedEdgeRunner.flush_pane
+    """A traced FISH session's spans, and for each pane flush, in flush
+    order, the tuples in its pane and the bytes of the entries it
+    gathered and of the last-index vector it fetched."""
+    flushed = []
+    real_flush = feed_fused.FusedEdgeRunner.flush_pane
+    real_gather = feed_fused._pane_gather
 
     def flush(self, sink):
         if self.has_pane and self.pane_fed:
-            planes.append({k: getattr(self, k).nbytes for k in
-                           ("pane_cnt", "pane_tab", "pane_last")})
-        return real(self, sink)
+            flushed.append({"pane_fed": self.pane_fed,
+                            "last": self.pane_last.nbytes})
+        return real_flush(self, sink)
+
+    def gather(tab, ws, ks):
+        out = real_gather(tab, ws, ks)
+        flushed[-1]["entries"] = out.nbytes
+        return out
 
     with pytest.MonkeyPatch.context() as mp:
         mp.setattr(feed_fused.FusedEdgeRunner, "flush_pane", flush)
+        mp.setattr(feed_fused, "_pane_gather", gather)
         # the last window is still open at close()
         spans = _session("fish", n=4 * WINDOW + FEED).tracer.spans
-    return spans, planes
+    return spans, flushed
 
 
 @pytest.fixture(scope="module")
@@ -138,25 +146,29 @@ def fish_spans(fish_run):
 
 
 def test_pane_flush_children_nest_and_cover_the_flush(fish_run):
-    fish_spans, planes = fish_run
+    fish_spans, flushed = fish_run
     flushes = sorted((s for s in fish_spans if s.name == "fused.pane_flush"),
                      key=lambda s: s.t0)
     kids = [s for s in fish_spans if s.name.startswith("fused.pane_flush.")]
     assert len(flushes) >= 4
-    assert len(kids) == 5 * len(flushes)  # three copies, a scan, a merge
+    assert len(kids) == 3 * len(flushes)  # a scan, a copy, a merge
     for k in kids:
         assert sum(_within(k, f) for f in flushes) == 1, k.name
     covered = sum(k.t1 - k.t0 for k in kids)
     assert covered >= 0.95 * sum(f.t1 - f.t0 for f in flushes)
-    assert len(planes) == len(flushes)
-    for f, nbytes in zip(flushes, planes):
+    assert len(flushed) == len(flushes)
+    for f, got in zip(flushes, flushed):
         mine = [k for k in kids if _within(k, f)]
         copies = {k.args["array"]: k.args["bytes"] for k in mine
                   if k.name == "fused.pane_flush.copy"}
-        assert copies == nbytes
+        assert copies == {"entries": got["entries"] + got["last"]}
         scan, = [k for k in mine if k.name == "fused.pane_flush.scan"]
         merge, = [k for k in mine if k.name == "fused.pane_flush.merge"]
-        assert scan.args["live"] == merge.args["entries"] > 0
+        # every tuple of the pane is a touched pair; the dedupe keeps
+        # one per live entry
+        assert scan.args["touched"] == got["pane_fed"]
+        assert 0 < scan.args["live"] <= scan.args["touched"]
+        assert scan.args["live"] == merge.args["entries"]
 
 
 def test_session_emit_once_per_operator_feed(fish_spans):
@@ -236,13 +248,9 @@ def _bundle():
         ("session.emit", 0.8, 0.9, {"partials": 0, "entries": 0}),
         ("session.feed", 1.0, 4.0, None),
         ("fused.pane_flush", 1.5, 3.5, None),
-        ("fused.pane_flush.copy", 1.5, 1.7, {"array": "pane_cnt",
-                                             "bytes": 2 * 10 ** 8}),
-        ("fused.pane_flush.copy", 1.7, 2.1, {"array": "pane_tab",
-                                             "bytes": 4 * 10 ** 8}),
-        ("fused.pane_flush.copy", 2.1, 2.1, {"array": "pane_last",
-                                             "bytes": 516}),
-        ("fused.pane_flush.scan", 2.1, 3.0, {"live": 10}),
+        ("fused.pane_flush.scan", 1.5, 2.4, {"touched": 16, "live": 10}),
+        ("fused.pane_flush.copy", 2.4, 3.0, {"array": "entries",
+                                             "bytes": 6 * 10 ** 8 + 516}),
         ("fused.pane_flush.merge", 3.0, 3.5, {"entries": 10}),
         ("session.emit", 3.6, 3.9, {"partials": 4, "entries": 10}),
         ("session.emit", 11.0, 12.0, None),  # after the window

@@ -1,8 +1,9 @@
 """Ahead-of-time compiles for a TPU v5e chip that is described, not attached.
 
 The fused segment function at the chip smoke's shapes (129 worker lanes x
-2^20+1 key rows x 16,384 tuples, associative-scan FIFO) and the three
-stream Pallas kernels at K=4096 slots x N=16,384 keys.  What the chip's
+2^20+1 key rows x 16,384 tuples, associative-scan FIFO), the pane flush's
+gather from its table, and the three stream Pallas kernels at K=4096
+slots x N=16,384 keys.  What the chip's
 compiler refuses (scoped-VMEM overflow, a program past 16 GB) fails here
 at no chip time.  Nothing runs, so this says nothing about results.
 
@@ -12,6 +13,7 @@ this file.
 """
 
 import os
+import re
 
 import pytest
 
@@ -101,6 +103,35 @@ def test_segment_compiles_for_v5e(one_chip, scheme, reset):
     # the per-key tables are donated: a continuing pane updates in place
     if not reset:
         assert ma.alias_size_in_bytes >= w1 * kcap1 * 2 * 4
+
+
+def test_pane_gather_reads_the_segment_layout_for_v5e(one_chip):
+    # the flush gathers from the table the segment wrote: the gather must
+    # take it in the layout the segment leaves it in, or the chip copies
+    # the whole table to relayout it before every flush
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    w1, kcap1 = WORKERS + 1, KEY_CAP + 1
+    sig = ("sg", FEED, w1, kcap1, 0, 0, True, True, "assoc")
+    dev, a = _segment_specs(spec, *sig[:6], True)
+    seg = feed_fused._get_seg_fn(sig).lower(dev, a).compile()
+    bucket = 4 * FEED
+    gather = feed_fused._pane_gather.lower(
+        spec((w1, kcap1, 2), jnp.int32), spec((bucket,), jnp.int32),
+        spec((bucket,), jnp.int32)).compile()
+    table = re.compile(rf"s32\[{w1},{kcap1},2\]\{{[^}}]*\}}")
+
+    def layouts(exe):
+        head = exe.as_text().split("\n", 1)[0]
+        return table.findall(head.split("entry_computation_layout=", 1)[1])
+
+    written, = layouts(seg)  # the fresh pane's table is an output only
+    read, = layouts(gather)
+    assert read == written
+    ma = gather.memory_analysis()
+    assert ma.output_size_in_bytes == bucket * 2 * 4
+    assert ma.temp_size_in_bytes < w1 * kcap1  # no copy of the table
 
 
 @pytest.mark.parametrize("kernel", ("store_probe", "fish_count",
