@@ -175,7 +175,8 @@ class EdgeAuditor:
             dmax = r._cands.shape[1]
         reset = r.has_pane and r.pane_tab is None
         return (r.scheme, n_pad, r._w1, r._kcap + 1, r_n, dmax,
-                r.has_pane, reset, r.fifo_impl)
+                r.has_pane, reset, r.fifo_impl,
+                r._slots + 1 if r.has_pane else 0)
 
     # -- budget assertions -------------------------------------------------
 

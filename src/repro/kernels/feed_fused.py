@@ -16,8 +16,10 @@ b. **FIFO** — the closed-form per-worker recurrence solved on device,
    either as one ``lax.scan`` (exact, the CPU default) or as
    ``jax.lax.associative_scan`` over a segmented maximum-accumulate
    (``fifo_impl="assoc"``, the depth-log parallel form, default on TPU);
-c. **keyed-state update** — per-(key, worker) pane aggregate tables
-   updated by scatter-add inside the same launch; panes sync to the host
+c. **keyed-state update** — per-(worker, pane slot) aggregate tables
+   updated by scatter-add inside the same launch (the host gives each key
+   a slot the first time it appears in the open pane, so the table
+   follows the pane's tuples, not the key space); panes sync to the host
    :class:`~repro.state.window.KeyedStateManager` only at pane boundaries
    and membership events (``merge_entries`` accumulates, so a pane can be
    synced mid-way and continue on zeroed device tables exactly).  The
@@ -37,10 +39,11 @@ Shape discipline: segment lengths pad to power-of-two buckets (min
 :data:`TRACE_COUNT` increments per trace for the compile-count
 regression test.  Everything sized per-key is a dense table of
 ``key_capacity + 1`` rows (row = key id, last row = phantom absorbing
-the padding lanes), everything per-worker has ``busy_len + 1`` lanes
-(last = phantom worker).  Worker-universe or key-capacity growth and
-ring rebuilds with a different point count change static shapes and
-recompile — rare, documented in DESIGN.md §11.
+the padding lanes), the pane has ``slots + 1`` rows (last = phantom
+slot), everything per-worker has ``busy_len + 1`` lanes (last = phantom
+worker).  Worker-universe or key-capacity growth and ring rebuilds with
+a different point count change static shapes and recompile — rare,
+documented in DESIGN.md §11.
 
 Semantics vs the reference oracle (DESIGN.md §6): SG/FG/PKG routing,
 counts, replicas and window aggregates are exact (timing carries an f32
@@ -79,7 +82,11 @@ _TRACE_COUNTER = GLOBAL_METRICS.counter("fused.trace_count")
 #: Shared disabled bundle for runners no session bound telemetry to.
 _NULL_TELEMETRY = Telemetry(enabled=False)
 MIN_BUCKET = 64  # smallest pow2 padding bucket for segment lengths
-KEY_CAP_LIMIT = 1 << 21  # dense per-key tables; larger key ids fall back
+#: Dense per-key tables; larger key ids fall back.  At 2^24 + 1 rows the
+#: per-key tables of a 128-worker edge (tracker, candidate memory, the
+#: 129-lane replica matrix, 2.28 GB as a v5e lays it out) and of its
+#: 32-worker merge edge fit one 16 GB chip with room for a growth copy.
+KEY_CAP_LIMIT = 1 << 24
 
 _SEG_CACHE: dict = {}  # static signature -> jitted segment function
 
@@ -376,19 +383,18 @@ def _route_fish(a, row):
 def _get_seg_fn(sig):
     """Build (or fetch) the jitted segment function for one static shape
     signature — (scheme, padded length, worker lanes, key rows, ring
-    points, candidate width, pane?, fresh pane?, fifo impl) is the
-    recompile boundary.
+    points, candidate width, pane?, fresh pane?, fifo impl, pane slot
+    rows) is the recompile boundary.
 
     Each phase runs under a ``jax.named_scope`` (``route/ring``,
-    ``route/tracker``, ``route/choose``, ``fifo``, ``pane/scatter``,
-    ``pane/replicas``, ``pane/last``, or
-    ``replicas`` without a pane), which names its operations in a device
-    trace's ``tf_op`` metadata; the compiled program is the same.  The
-    module is ``jit_seg_<scheme>``."""
+    ``route/tracker``, ``route/choose``, ``fifo``, ``replicas``, and with
+    a pane ``pane/scatter`` and ``pane/last``), which names its operations
+    in a device trace's ``tf_op`` metadata; the compiled program is the
+    same.  The module is ``jit_seg_<scheme>``."""
     fn = _SEG_CACHE.get(sig)
     if fn is not None:
         return fn
-    scheme, n_pad, w1, kcap1, r_n, dmax, has_pane, reset, fifo_impl = sig
+    scheme, n_pad, w1, kcap1, r_n, dmax, has_pane, reset, fifo_impl, s1 = sig
     phantom_w = w1 - 1
     fifo = _fifo_scan if fifo_impl == "scan" else _fifo_assoc
 
@@ -446,36 +452,35 @@ def _get_seg_fn(sig):
         out["fin"] = fin
         out["busy"] = busy
         out["counts"] = counts
+        with jax.named_scope("replicas"):
+            out["repl"] = a["repl"].at[a["keys"], workers].set(True)
         if has_pane:
             with jax.named_scope("pane/scatter"):
                 # one stacked scatter updates value and count planes
                 # together, through a flat row index (1-D indexed scatters
-                # lower to a cheaper XLA scatter than 2-D ones on CPU); its
-                # count plane then gives the replica update as a dense OR
-                # — both measurably cheaper than separate 2-D scatters
+                # lower to a cheaper XLA scatter than 2-D ones on CPU)
                 vc = jnp.stack([jnp.where(a["valid"], a["vals"], 0),
                                 a["valid"].astype(jnp.int32)], axis=-1)
-                # worker-major flat index into the (w1, kcap1) planes
-                flat = workers * kcap1 + a["keys"]
+                # worker-major flat index into the (w1, s1) planes, by the
+                # pane slot the host gave each tuple's key
+                flat = workers * s1 + a["slots"]
                 # `reset` marks the first segment of a pane: the tables
                 # start from in-jit zeros (a fused memset) instead of
                 # round-tripping an eagerly allocated zero buffer through
                 # the launch
-                base = (jnp.zeros((w1 * kcap1, 2), jnp.int32) if reset
-                        else a["pane_tab"].reshape(w1 * kcap1, 2))
+                base = (jnp.zeros((w1 * s1, 2), jnp.int32) if reset
+                        else a["pane_tab"].reshape(w1 * s1, 2))
                 # indices are in-bounds by construction (the phantom
-                # worker lane and phantom key row absorb padding), so
+                # worker lane and phantom slot row absorb padding), so
                 # skipping the per-element bounds check measurably speeds
                 # the CPU scatter
                 pane = base.at[flat].add(
-                    vc, mode="promise_in_bounds").reshape(w1, kcap1, 2)
+                    vc, mode="promise_in_bounds").reshape(w1, s1, 2)
             out["pane_tab"] = pane
             # each tuple's worker: with the host's keys it names every
             # (worker, key) entry the pane touched, so the flush gathers
             # those alone instead of reading the dense planes
             out["workers"] = workers
-            with jax.named_scope("pane/replicas"):
-                out["repl"] = a["repl"] | (pane[:, :, 1] > 0).T
             with jax.named_scope("pane/last"):
                 gidx = a["seg_base"] + jnp.arange(n_pad, dtype=jnp.int32)
                 gidx = jnp.where(a["valid"], gidx, -1)
@@ -485,9 +490,6 @@ def _get_seg_fn(sig):
                               gidx[None, :], -1), axis=1)
                 out["pane_last"] = (seg_last if reset else
                                     jnp.maximum(a["pane_last"], seg_last))
-        else:
-            with jax.named_scope("replicas"):
-                out["repl"] = a["repl"].at[a["keys"], workers].set(True)
         return out
 
     # the per-scheme name makes the module `jit_seg_<scheme>`
@@ -496,13 +498,32 @@ def _get_seg_fn(sig):
     return fn
 
 
-def pane_gather(tab, ws, ks):
-    """The (value, count) entries of the (w1, kcap1, 2) pane table at the
-    (worker, key) pairs ``(ws[i], ks[i])``, one row each.  The 2-D index
-    reads the table in its own layout, never reshaped; padding pairs name
-    the phantom lane and key row, which only ever hold zeros.  The module
-    is ``jit_pane_gather``, outside the ``jit_seg`` programs."""
-    return tab.at[ws, ks].get(mode="promise_in_bounds")
+def pane_gather(tab, ws, slots):
+    """The (value, count) entries of the (w1, s1, 2) pane table at the
+    (worker, slot) pairs ``(ws[i], slots[i])``, one row each.  The 2-D
+    index reads the table in its own layout, never reshaped; padding pairs
+    name the phantom lane and slot row, which only ever hold zeros.  The
+    module is ``jit_pane_gather``, outside the ``jit_seg`` programs."""
+    return tab.at[ws, slots].get(mode="promise_in_bounds")
+
+
+def hot_set_points(trk, prev, theta):
+    """FISH's hot set at an epoch point, on the device: the keys whose
+    tracker share exceeds ``theta`` (the phantom row dropped), how many
+    there are, and how many entered or left since the ``prev`` mask.
+
+    The total is a float32 sum in XLA's reduction order, which is not
+    numpy's: the two may differ in their last bits, so a key whose count
+    lies within that rounding (a relative 1e-6 or so at 2^24 rows) of
+    ``theta * total`` may be classed on the other side than a host sum
+    would class it.  The routing's own classification is float32 too."""
+    t = trk[:-1]
+    total = jnp.sum(t)
+    hot = (t > theta * total) & (total > 0.0)
+    return jnp.sum(hot), jnp.sum(hot != prev), hot
+
+
+_hot_set_points = jax.jit(hot_set_points)
 
 
 _pane_gather = jax.jit(pane_gather)
@@ -523,11 +544,19 @@ class FusedEdgeRunner:
     the host copies authoritative so event handling and metrics never
     need a separate sync.  ``host_sync`` folds the replica matrix back
     into the grouper — called before metrics/close and membership events.
+
+    The open pane is keyed by slot, not by key id: the host gives each key
+    the next free slot the first time it appears in the pane (a dense
+    key -> slot map, reset at the keys it touched when the pane is
+    flushed).  A pane never holds more tuples than the sink's window
+    stride, so its distinct keys, and the table's rows, are at most
+    ``min(_bucket(stride), key capacity)`` at any key space.
     """
 
     def __init__(self, grouper, state, sink, telemetry=None):
         self.scheme = grouper.name
         self.has_pane = sink is not None
+        self._pane_tuples = sink.op.stride if sink is not None else 0
         self.fifo_impl = ("assoc" if jax.default_backend() == "tpu"
                           else "scan")
         # ISSUE 9: launch/pane counters live in the metrics registry; the
@@ -542,14 +571,19 @@ class FusedEdgeRunner:
             "fused.host_syncs", scheme=self.scheme)
         self._c_pane_gathers = self.tel.metrics.counter(
             "fused.pane_gathers", scheme=self.scheme)
+        self._c_key_regrows = self.tel.metrics.counter(
+            "fused.key_regrows", scheme=self.scheme)
         self._feed_base_dispatches = 0
-        self._prev_hot: set = set()   # fish hot set at the last epoch point
+        self._prev_hot = None     # fish hot mask at the last epoch point
         self._fish_epoch_idx = -1
         self._fish_epochs_crossed = 0
         self.pane_fed = 0         # tuples in the device pane, unsynced
         # (workers, keys) of the open pane's valid tuples, one pair of
         # arrays per launch: the (w, k) entries the flush gathers
         self._pane_pairs: list = []
+        self._slot_of = None      # dense key -> pane slot + 1 (0 = none)
+        self._n_slots = 0         # slots in use in the open pane
+        self._slots = 0           # slot rows of the pane table (pow2)
         self._kcap = 0
         self._w1 = 0
         self._dmax = 1 if self.scheme == "fg" else (
@@ -565,7 +599,7 @@ class FusedEdgeRunner:
         self.trk = None
         self.m_k = None
         self.repl = None
-        self.pane_tab = None      # (w1, kcap1, 2): value / count planes
+        self.pane_tab = None      # (w1, slots + 1, 2): value / count planes
         self.pane_last = None
         self._repl_synced = None  # host mirror of already-synced pairs
 
@@ -585,10 +619,41 @@ class FusedEdgeRunner:
             new_kcap = _pow2_at_least(max(kmax + 1, MIN_BUCKET))
         if w1 == self._w1 and new_kcap == self._kcap:
             return
-        old_k, old_w = self._kcap, self._w1
+        old_k, old_w, old_s = self._kcap, self._w1, self._slots
+        if new_kcap != old_k:
+            with self.tel.tracer.span("fused.key_regrow", cat="fused",
+                                      kcap=new_kcap) as regrow_span:
+                self._grow_keys(old_k, new_kcap)
+                self._grow_repl(old_k, old_w, new_kcap, w1)
+                regrow_span.set(bytes=self._key_table_bytes())
+            self._c_key_regrows.add(1)
+        else:
+            self._grow_repl(old_k, old_w, new_kcap, w1)
+        if self.has_pane:
+            # the pane never holds more distinct keys than its tuples or
+            # than the key capacity, whichever is fewer
+            self._slots = min(_bucket(self._pane_tuples), new_kcap)
+            if self.pane_tab is not None and (w1, self._slots) != (old_w,
+                                                                  old_s):
+                # an empty (flushed) pane stays None — the next launch's
+                # `reset` variant rebuilds it at the new shape from zeros
+                self.pane_tab = _grow_pane(self.pane_tab, old_w, old_s, w1,
+                                           self._slots)
+                self.pane_last = _grow_last(self.pane_last, old_w, w1)
+        grew_w = w1 != self._w1
+        self._kcap = new_kcap
+        self._w1 = w1
+        if grew_w:
+            self.refresh_membership(grouper, state)
+
+    def _grow_keys(self, old_k: int, new_kcap: int) -> None:
+        """Grow the per-key vectors to ``new_kcap`` ids: a copy of each,
+        and a recompile of every launch that reads them."""
         kcap1 = new_kcap + 1
         self._hash_arr = _grow1(self._hash_arr, old_k, new_kcap, np.uint32)
         self._hash_ok = _grow1(self._hash_ok, old_k, new_kcap, np.bool_)
+        if self.has_pane:
+            self._slot_of = _grow1(self._slot_of, old_k, new_kcap, np.int32)
         if self.scheme in _RING_SCHEMES and new_kcap <= (1 << 14):
             # prefill the whole ring-hash cache at the (rare) resize so
             # steady-state feeds never touch SHA-1; for sparse key spaces
@@ -596,22 +661,24 @@ class FusedEdgeRunner:
             self._fill_hashes(np.flatnonzero(~self._hash_ok))
         # the old phantom key row (index old_k) is dropped by the [:old_k]
         # copy — it only ever holds the padding lanes' sink entries
-        self.trk = _grow_dev1(self.trk, old_k, kcap1, jnp.float32)
-        self.m_k = _grow_dev1(self.m_k, old_k, kcap1, jnp.int32)
-        self.repl = _grow_dev2(self.repl, old_k, old_w, kcap1, w1, jnp.bool_)
+        if self.scheme in ("dc", "wc", "fish"):
+            self.trk = _grow_dev1(self.trk, old_k, kcap1, jnp.float32)
+        if self.scheme == "fish":
+            self.m_k = _grow_dev1(self.m_k, old_k, kcap1, jnp.int32)
+
+    def _grow_repl(self, old_k: int, old_w: int, new_kcap: int,
+                   w1: int) -> None:
+        """Grow the (key, worker) replica matrix and its host mirror."""
+        self.repl = _grow_dev2(self.repl, old_k, old_w, new_kcap + 1, w1,
+                               jnp.bool_)
         self._repl_synced = _grow_host2(self._repl_synced, old_k, old_w,
-                                        kcap1, w1)
-        if self.has_pane and self.pane_tab is not None:
-            # an empty (flushed) pane stays None — the next launch's
-            # `reset` variant rebuilds it at the new shape from zeros
-            self.pane_tab = _grow_dev3(self.pane_tab, old_k, old_w,
-                                       kcap1, w1)
-            self.pane_last = _grow_last(self.pane_last, old_w, w1)
-        grew_w = w1 != self._w1
-        self._kcap = new_kcap
-        self._w1 = w1
-        if grew_w:
-            self.refresh_membership(grouper, state)
+                                        new_kcap + 1, w1)
+
+    def _key_table_bytes(self) -> int:
+        """Bytes of the per-key tables, on the device and on the host."""
+        return sum(int(t.nbytes) for t in (
+            self.trk, self.m_k, self.repl, self._repl_synced,
+            self._hash_arr, self._hash_ok, self._slot_of) if t is not None)
 
     def refresh_membership(self, grouper, state) -> None:
         """Rebuild the device ring table + live-set arrays after a
@@ -651,21 +718,47 @@ class FusedEdgeRunner:
                     sink.op, keys_arr, payload=values).astype(np.int32)
 
     def _fill_hashes(self, miss: np.ndarray) -> None:
-        if miss.shape[0]:
-            # inlined hash32 for plain int keys (same SHA-1 bucket as
-            # chash.hash32): skips the per-key canonicalise/dispatch
-            sha1, fb = _sha1, int.from_bytes
-            self._hash_arr[miss] = np.fromiter(
-                (fb(sha1(repr(k).encode("utf-8")).digest()[:4], "big")
-                 for k in miss.tolist()),
-                dtype=np.uint32, count=miss.shape[0])
-            self._hash_ok[miss] = True
+        """Hash the keys ``miss`` into the ring-hash cache, under the span
+        ``fused.hash_fill`` (arg ``keys``, 0 when every key was cached)."""
+        with self.tel.tracer.span("fused.hash_fill", cat="fused",
+                                  keys=int(miss.shape[0])):
+            if miss.shape[0]:
+                # inlined hash32 for plain int keys (same SHA-1 bucket as
+                # chash.hash32): skips the per-key canonicalise/dispatch
+                sha1, fb = _sha1, int.from_bytes
+                self._hash_arr[miss] = np.fromiter(
+                    (fb(sha1(repr(k).encode("utf-8")).digest()[:4], "big")
+                     for k in miss.tolist()),
+                    dtype=np.uint32, count=miss.shape[0])
+                self._hash_ok[miss] = True
 
     def _hashes(self, keys_arr: np.ndarray) -> np.ndarray:
         ok = self._hash_ok[keys_arr]
-        if not ok.all():
-            self._fill_hashes(np.unique(keys_arr[~ok]))
+        self._fill_hashes(np.unique(keys_arr[~ok]) if not ok.all()
+                          else keys_arr[:0])
         return self._hash_arr[keys_arr]
+
+    def _pane_slots(self, keys: np.ndarray) -> np.ndarray:
+        """Each key's slot in the open pane, giving the next free slots
+        to keys new to it (ascending key order), under the span
+        ``fused.pane_slots`` (args ``new`` and ``slots`` in use)."""
+        with self.tel.tracer.span("fused.pane_slots",
+                                  cat="fused") as slot_span:
+            slot = self._slot_of[keys]
+            fresh = keys[:0]
+            if not slot.all():
+                fresh = np.unique(keys[slot == 0])
+                n0 = self._n_slots
+                self._n_slots = n0 + fresh.shape[0]
+                if self._n_slots > self._slots:
+                    raise RuntimeError(
+                        f"fused pane: {self._n_slots} keys in one pane "
+                        f"exceed its {self._slots} slots")
+                self._slot_of[fresh] = np.arange(n0 + 1, self._n_slots + 1,
+                                                 dtype=np.int32)
+                slot = self._slot_of[keys]
+            slot_span.set(new=int(fresh.shape[0]), slots=self._n_slots)
+        return slot - 1
 
     def run_segment(self, grouper, state, lo: int, hi: int) -> np.ndarray:
         """One fused launch for tuples [lo, hi) of the current feed.
@@ -744,6 +837,9 @@ class FusedEdgeRunner:
             vals = np.zeros(n_pad, np.int32)
             vals[:m] = self._feed_vals[lo:hi]
             a["vals"] = vals
+            slots = np.full(n_pad, self._slots, np.int32)  # pad -> phantom
+            slots[:m] = self._pane_slots(self._feed_keys[lo:hi])
+            a["slots"] = slots
             reset = self.pane_tab is None  # first segment of a fresh pane
             if not reset:
                 dev["pane_tab"] = self.pane_tab
@@ -751,7 +847,7 @@ class FusedEdgeRunner:
             a["seg_base"] = np.int32(state.offset + lo)
 
         sig = (scheme, n_pad, w1, kcap1, r_n, dmax, self.has_pane, reset,
-               self.fifo_impl)
+               self.fifo_impl, self._slots + 1 if self.has_pane else 0)
         prep_span.done()
         # the one device dispatch: routing, FIFO and state-scatter run as
         # a single fused launch, so the phases share this span; the device
@@ -845,22 +941,25 @@ class FusedEdgeRunner:
 
     def _fish_epoch_points(self, grouper, state, lo: int, hi: int) -> None:
         """Per-epoch FISH timeline (telemetry-enabled only): hot-set size
-        and churn read off the *device* tracker after a segment that
-        crossed one or more epoch boundaries, plus the per-worker
-        imbalance at that instant.  ``np.asarray`` copies the tracker to
-        the host (a view only on the CPU backend), one read per segment
-        that crossed an epoch, never per tuple."""
+        and churn computed on the device from the tracker after a segment
+        that crossed one or more epoch boundaries, against the hot mask of
+        the previous point kept there, plus the per-worker imbalance at
+        that instant.  Two numbers are fetched, one read per segment that
+        crossed an epoch, never per tuple."""
         epoch_idx = self._fish_epoch_idx
         self.tel.ctx.epoch_idx = epoch_idx
-        trk = np.asarray(self.trk)[:-1]  # drop the phantom padding row
-        total = float(trk.sum())
+        k = self.trk.shape[0] - 1  # the phantom padding row is dropped
+        prev = self._prev_hot
+        if prev is None or prev.shape[0] != k:
+            # key ids past the old capacity were never hot
+            prev = _grow_dev1(prev, 0 if prev is None else prev.shape[0],
+                              k, jnp.bool_)
         theta = grouper.params.theta(grouper.num_workers)
-        hot = (set(np.flatnonzero(trk > theta * total).tolist())
-               if total > 0.0 else set())
-        churn = len(hot ^ self._prev_hot)
-        self._prev_hot = hot
+        size, churn, self._prev_hot = _hot_set_points(
+            self.trk, prev, np.float32(theta))
+        size, churn = int(size), int(churn)
         tl = self.tel.timeline
-        tl.point("fish.hot_set_size", len(hot), epoch_idx=epoch_idx)
+        tl.point("fish.hot_set_size", size, epoch_idx=epoch_idx)
         tl.point("fish.hot_set_churn", churn, epoch_idx=epoch_idx)
         counts = grouper.assigned_counts
         act = self._act
@@ -871,7 +970,7 @@ class FusedEdgeRunner:
                      epoch_idx=epoch_idx)
         self.tel.tracer.instant(
             "fish.epoch_decay", cat="fish", epoch=epoch_idx,
-            crossed=int(self._fish_epochs_crossed), hot_set=len(hot))
+            crossed=int(self._fish_epochs_crossed), hot_set=size)
 
     # -- host sync points ---------------------------------------------------
     def flush_pane(self, sink) -> None:
@@ -903,12 +1002,15 @@ class FusedEdgeRunner:
         flat = np.unique(ws_all.astype(np.int64) * kcap1 + ks_all)
         ws, ks = np.divmod(flat, kcap1)
         live = int(flat.shape[0])
-        # one static bucket per pane size; the phantom lane and key row pad
+        # one static bucket per pane size; the phantom lane and slot pad
         bucket = _bucket(self.pane_fed)
         ws_pad = np.full(bucket, self._w1 - 1, np.int32)
         ws_pad[:live] = ws
-        ks_pad = np.full(bucket, self._kcap, np.int32)
-        ks_pad[:live] = ks
+        sl_pad = np.full(bucket, self._slots, np.int32)
+        sl_pad[:live] = self._slot_of[ks] - 1
+        # the pane's slots are free again: reset the map where it was set
+        self._slot_of[ks] = 0
+        self._n_slots = 0
         starts = np.concatenate(
             [[0], np.flatnonzero(ws[1:] != ws[:-1]) + 1, [live]]).tolist()
         runs = list(zip(starts[:-1], starts[1:]))  # one run per worker
@@ -916,7 +1018,7 @@ class FusedEdgeRunner:
         with tracer.span("fused.pane_flush.copy", cat="fused",
                          array="entries") as copy_span:
             got, last = jax.device_get(
-                (_pane_gather(self.pane_tab, ws_pad, ks_pad),
+                (_pane_gather(self.pane_tab, ws_pad, sl_pad),
                  self.pane_last))
             self._c_pane_gathers.add(1)
             copy_span.set(bytes=got.nbytes + last.nbytes)
@@ -980,12 +1082,12 @@ def _grow_dev2(arr, old_k, old_w, kcap1, w1, dtype):
     return out.at[:old_k, :old_w].set(arr[:old_k, :old_w])
 
 
-def _grow_dev3(arr, old_k, old_w, kcap1, w1):
-    # pane tables are worker-major: (w1, kcap1, 2)
-    out = jnp.zeros((w1, kcap1, 2), jnp.int32)
-    if arr is None:
-        return out
-    return out.at[:old_w, :old_k, :].set(arr[:old_w, :old_k, :])
+def _grow_pane(arr, old_w, old_s, w1, slots):
+    # pane tables are worker-major: (w1, slots + 1, 2).  The old phantom
+    # slot row (index old_s) only holds the padding lanes' entries in the
+    # old phantom lane: the [:old_w, :old_s] copy drops both
+    out = jnp.zeros((w1, slots + 1, 2), jnp.int32)
+    return out.at[:old_w, :old_s, :].set(arr[:old_w, :old_s, :])
 
 
 def _grow_host2(arr, old_k, old_w, kcap1, w1):

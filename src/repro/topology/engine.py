@@ -599,14 +599,15 @@ def _emit_args(span, partials, emission) -> None:
              entries=0 if emission is None else len(emission[0]))
 
 
-def _emit_partials(partials, finishes: np.ndarray, in_roots: np.ndarray,
-                   fallback_time: float):
+def _emit_partials(partials, finishes, in_roots, fallback_time: float):
     """The stream a batch of flushed window partials emits downstream: one
     partial-aggregate tuple per state entry, keyed by the aggregation key
     and released when its worker flushed the window (the finish time of
     that worker's last tuple in the window; ``fallback_time`` covers
     entries whose anchor tuple never finished — the serving engine's
-    dropped requests).  Partial tuples carry no payload column.  Sessions
+    dropped requests).  ``finishes`` and ``in_roots`` are read at each
+    partial's last tuple index only: arrays, or a session's
+    :class:`_History`.  Partial tuples carry no payload column.  Sessions
     call this per feed with the windows that closed during it (incremental
     emission — ISSUE 6 satellite) and once more at close with the
     remainder."""
@@ -682,6 +683,38 @@ class SimulatorEngine:
         return _run_via_session(self, topology, source, events)
 
 
+class _History:
+    """Per-tuple values of a stage's input, one array per feed, read at
+    absolute tuple indices without concatenating them: a window's emit
+    reads one row per partial, where a concatenation copied the whole
+    history on every window and grew with the session.  ``max`` is the
+    running maximum of every value appended."""
+
+    __slots__ = ("parts", "ends", "max")
+
+    def __init__(self):
+        self.parts: List[np.ndarray] = []
+        self.ends: List[int] = [0]
+        self.max = -np.inf
+
+    def __len__(self) -> int:
+        return self.ends[-1]
+
+    def append(self, values: np.ndarray) -> None:
+        self.parts.append(values)
+        self.ends.append(self.ends[-1] + values.shape[0])
+        if values.shape[0]:
+            self.max = max(self.max, float(values.max()))
+
+    def __getitem__(self, idx: np.ndarray) -> np.ndarray:
+        idx = np.asarray(idx, dtype=np.int64)
+        part = np.searchsorted(self.ends, idx, side="right") - 1
+        dtype = self.parts[0].dtype if self.parts else np.float64
+        return np.array([self.parts[p][i - self.ends[p]]
+                         for p, i in zip(part.tolist(), idx.tolist())],
+                        dtype=dtype)
+
+
 class _SimEdge:
     """One grouped edge's carried session state (DSPE simulator)."""
 
@@ -702,8 +735,8 @@ class _SimEdge:
         self.mgr = mgr
         self.lats: List[np.ndarray] = []
         self.n = 0
-        self.finishes: List[np.ndarray] = []  # operator stages only
-        self.roots: List[np.ndarray] = []     # operator stages only
+        self.finishes = _History()   # operator stages only
+        self.roots = _History()      # operator stages only
         self.srep: Optional[StateReport] = None
         self.emitted = 0             # window partials already sent downstream
         self.dispatches = 0          # fused-mode device launches (ISSUE 6)
@@ -825,13 +858,10 @@ class SimulatorSession(_BaseSession):
                     if rest or st.emitted == 0:
                         with self.telemetry.tracer.span(
                                 "session.emit", cat="session") as emit_span:
-                            fin = (np.concatenate(st.finishes)
-                                   if st.finishes else np.empty(0))
-                            roots = (np.concatenate(st.roots) if st.roots
-                                     else np.empty(0, dtype=np.int64))
                             emission = _emit_partials(
-                                rest, fin, roots,
-                                float(fin.max()) if fin.size else 0.0)
+                                rest, st.finishes, st.roots,
+                                st.finishes.max if len(st.finishes)
+                                else 0.0)
                             _emit_args(emit_span, rest, emission)
                         streams[st.stage.name] = emission
                         st.emitted = len(st.mgr.partials)
@@ -933,10 +963,8 @@ class SimulatorSession(_BaseSession):
                 emission = None
                 if fresh:
                     st.emitted += len(fresh)
-                    fin = np.concatenate(st.finishes)
-                    roots = np.concatenate(st.roots)
-                    emission = _emit_partials(fresh, fin, roots,
-                                              float(fin.max()))
+                    emission = _emit_partials(fresh, st.finishes, st.roots,
+                                              st.finishes.max)
                 _emit_args(emit_span, fresh, emission)
             return emission
         else:  # intermediate stage: release transformed tuples

@@ -72,9 +72,8 @@ def _scopes(scheme, has_pane):
              "fg": ["route/ring", "route/choose"],
              "pkg": ["route/ring", "route/choose"]}.get(
         scheme, ["route/ring", "route/tracker", "route/choose"])
-    tail = (["pane/scatter", "pane/replicas", "pane/last"] if has_pane
-            else ["replicas"])
-    return route + ["fifo"] + tail
+    tail = ["pane/scatter", "pane/last"] if has_pane else []
+    return route + ["fifo", "replicas"] + tail
 
 
 @pytest.mark.parametrize("scheme", SCHEMES)
@@ -195,6 +194,31 @@ def test_launch_marks_its_compile_and_fish_epoch_reads(fish_spans):
     assert points and all(any(_within(p, s) for s in segs) for p in points)
 
 
+def test_pane_slot_hash_fill_and_key_regrow_spans(fish_spans):
+    segs = [s for s in fish_spans if s.name == "fused.segment"]
+    slots = [s for s in fish_spans if s.name == "fused.pane_slots"]
+    # one slot assignment per launch of the windowed edge, inside it
+    assert slots and all(any(_within(p, s) for s in segs) for p in slots)
+    for p in slots:
+        assert 0 <= p.args["new"] <= p.args["slots"] <= WINDOW
+    # a flush frees the pane's slots: some launch starts from none
+    assert any(p.args["new"] == p.args["slots"] > 0 for p in slots[1:])
+    feeds = [s for s in fish_spans if s.name == "fused.begin_feed"]
+    regrows = [s for s in fish_spans if s.name == "fused.key_regrow"]
+    assert len(regrows) >= 2  # each edge sizes its key tables
+    for r in regrows:
+        assert r.args["bytes"] > 0 and r.args["kcap"] & (r.args["kcap"] - 1) \
+            == 0
+    fills = [s for s in fish_spans if s.name == "fused.hash_fill"]
+    assert fills and all(any(_within(f, s) for s in feeds + regrows)
+                         for f in fills)
+    # every key both ring-hashing edges saw was hashed, none twice per edge
+    keys = zipf_time_evolving(4 * WINDOW + FEED, num_keys=60_000, z=1.1,
+                              seed=3)
+    distinct = np.unique(keys).shape[0]
+    assert distinct <= sum(f.args["keys"] for f in fills) <= 2 * distinct
+
+
 # -- the spans on the profiler's clock ----------------------------------------
 
 
@@ -253,7 +277,12 @@ def _bundle():
                                              "bytes": 6 * 10 ** 8 + 516}),
         ("fused.pane_flush.merge", 3.0, 3.5, {"entries": 10}),
         ("session.emit", 3.6, 3.9, {"partials": 4, "entries": 10}),
+        ("fused.pane_slots", 0.1, 0.15, {"new": 7, "slots": 7}),
+        ("fused.pane_slots", 1.1, 1.2, {"new": 3, "slots": 10}),
+        ("fused.hash_fill", 0.05, 0.08, {"keys": 7}),
+        ("fused.hash_fill", 1.05, 1.06, {"keys": 0}),
         ("session.emit", 11.0, 12.0, None),  # after the window
+        ("fused.pane_slots", 11.1, 11.5, {"new": 0, "slots": 10}),
     ]
     return {"spans": spans, "window": (0.0, 10.0)}
 
@@ -264,6 +293,8 @@ READINGS = {
     "pane_scan_ms_per_flush.sat": 900.0,
     "pane_merge_ms_per_flush.sat": 500.0,
     "session_emit_ms_per_feed.sat": 200.0,
+    "pane_slot_ms_per_feed.sat": 75.0,
+    "hash_fill_ms_per_feed.sat": 20.0,
 }
 
 

@@ -1,12 +1,14 @@
 """The pane flush gathers only the entries its tuples touched.
 
-Every valid tuple adds 1 to the count of exactly one (worker, key) entry
-of the device pane table, so the distinct pairs the launches recorded
-are exactly the table's live entries.  Each case below reads the dense
-planes before every flush, builds the entries a ``flatnonzero`` scan of
-the count plane finds, and checks that the flush hands the manager the
-same entries in the same order: worker, keys, values, counts and last
-index, int64 columns, per worker with keys ascending."""
+The device pane table is keyed by (worker, pane slot), the slot a key
+got on the host the first time it appeared in the open pane.  Every
+valid tuple adds 1 to the count of exactly one entry, so the distinct
+pairs the launches recorded are exactly the table's live entries.  Each
+case below reads the planes before every flush, builds the entries a
+``flatnonzero`` scan of the count plane finds, maps each slot back to its
+key, and checks that the flush hands the manager the same entries in the
+same order: worker, keys, values, counts and last index, int64 columns,
+per worker with keys ascending."""
 
 from __future__ import annotations
 
@@ -32,9 +34,18 @@ def _dense_entries(runner):
     """The entries the dense scan of the open pane finds, in its order."""
     tab = np.asarray(runner.pane_tab)
     last = np.asarray(runner.pane_last)
+    assert tab.shape == (runner._w1, runner._slots + 1, 2)
+    # slot -> key, from the host's key -> slot map
+    slotted = np.flatnonzero(runner._slot_of)
+    key_of = np.full(tab.shape[1], -1, np.int64)
+    key_of[runner._slot_of[slotted] - 1] = slotted
     cnt = tab[:, :, 1]
     flat = np.flatnonzero(cnt)
-    ws, ks = np.divmod(flat, cnt.shape[1])
+    ws, sl = np.divmod(flat, cnt.shape[1])
+    ks = key_of[sl]
+    assert (ks >= 0).all()  # every live entry sits in an assigned slot
+    order = np.lexsort((ks, ws))  # worker-major, keys ascending
+    ws, ks, flat = ws[order], ks[order], flat[order]
     vs = tab.reshape(-1, 2)[flat, 0].astype(np.int64)
     cs = tab.reshape(-1, 2)[flat, 1].astype(np.int64)
     starts = np.concatenate(

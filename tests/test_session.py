@@ -453,3 +453,24 @@ def test_pane_sliding_windows_exact_under_churn_multi_feed(keys):
     assert st["merged"] == direct_aggregate(keys, op)
     assert st["windows"] == len(range(0, keys.shape[0], 500))
     assert st["migration_bytes"] > 0
+
+
+def test_history_reads_rows_as_a_concatenation_would():
+    """The session's per-feed history reads rows at absolute indices, across
+    and inside its parts, and keeps the running maximum of all values."""
+    from repro.topology.engine import _History
+
+    rng = np.random.default_rng(3)
+    parts = [rng.random(n) for n in (5, 0, 1, 7, 3)]
+    h = _History()
+    for p in parts:
+        h.append(p)
+    whole = np.concatenate(parts)
+    idx = np.array([15, 0, 4, 5, 6, 12, 13, 5], dtype=np.int64)
+    assert len(h) == whole.shape[0]
+    np.testing.assert_array_equal(h[idx], whole[idx])
+    assert h[idx].dtype == whole.dtype
+    assert h.max == whole.max()
+    roots = _History()
+    roots.append(np.arange(4, dtype=np.int64))
+    assert roots[np.array([], dtype=np.int64)].dtype == np.int64

@@ -1,9 +1,10 @@
 """Ahead-of-time compiles for a TPU v5e chip that is described, not attached.
 
-The fused segment function at the chip smoke's shapes (129 worker lanes x
-2^20+1 key rows x 16,384 tuples, associative-scan FIFO), the pane flush's
-gather from its table, and the three stream Pallas kernels at K=4096
-slots x N=16,384 keys.  What the chip's
+The fused segment function at the benchmark's shapes (129 worker lanes x
+16,384 tuples, associative-scan FIFO, a pane of 65,536 slots) at 2^20+1
+and at 2^24+1 key rows (the 10^7-key ZF cell), the pane flush's gather
+from its table, and the three stream Pallas kernels at K=4096 slots x
+N=16,384 keys.  What the chip's
 compiler refuses (scoped-VMEM overflow, a program past 16 GB) fails here
 at no chip time.  Nothing runs, so this says nothing about results.
 
@@ -26,8 +27,9 @@ from repro.kernels import store_probe as _store_probe
 
 HBM_BYTES = 16 * 10 ** 9  # one v5e chip
 WORKERS = 128
-KEY_CAP = 1 << 20
+KEY_CAPS = (1 << 20, 1 << 24)
 FEED = 16_384
+SLOTS = 4 * FEED  # a 65,536-tuple window's pane
 RING_POINTS = 64 * WORKERS  # 64 virtual nodes per worker (core/baselines)
 K_SLOTS, N_KEYS = 4_096, 16_384
 
@@ -54,14 +56,15 @@ def one_chip():
     compilation_cache.reset_cache()
 
 
-def _segment_specs(spec, scheme, n_pad, w1, kcap1, r_n, dmax, reset):
+def _segment_specs(spec, scheme, n_pad, w1, kcap1, r_n, dmax, reset, s1):
     """(dev, a) argument shapes of one fused segment launch, laid out as
     :meth:`FusedEdgeRunner.run_segment` builds them (with a pane)."""
     i32, f32, u32 = jnp.int32, jnp.float32, jnp.uint32
     a = {"keys": spec((n_pad,), i32), "m": spec((), i32),
          "t": spec((n_pad,), f32), "busy": spec((w1,), f32),
          "caps": spec((w1,), f32), "counts": spec((w1,), i32),
-         "vals": spec((n_pad,), i32), "seg_base": spec((), i32)}
+         "vals": spec((n_pad,), i32), "slots": spec((n_pad,), i32),
+         "seg_base": spec((), i32)}
     dev = {"repl": spec((kcap1, w1), jnp.bool_)}
     if scheme == "sg":
         a.update(act=spec((w1,), i32), a_live=spec((), i32),
@@ -79,48 +82,57 @@ def _segment_specs(spec, scheme, n_pad, w1, kcap1, r_n, dmax, reset):
                  ecaps=spec((w1,), f32), do_tick=spec((), i32),
                  elapsed=spec((), f32))
     if not reset:
-        dev.update(pane_tab=spec((w1, kcap1, 2), i32),
+        dev.update(pane_tab=spec((w1, s1, 2), i32),
                    pane_last=spec((w1,), i32))
     return dev, a
 
 
+def _sig(scheme, kcap, reset):
+    w1 = WORKERS + 1
+    r_n = 0 if scheme == "sg" else RING_POINTS
+    dmax = {"sg": 0, "pkg": 2}.get(scheme, WORKERS)
+    return (scheme, FEED, w1, kcap + 1, r_n, dmax, True, reset, "assoc",
+            SLOTS + 1)
+
+
+@pytest.mark.parametrize("kcap", KEY_CAPS, ids=("2p20", "2p24"))
 @pytest.mark.parametrize("reset", (True, False), ids=("fresh", "continuing"))
 @pytest.mark.parametrize("scheme", ("sg", "pkg", "fish"))
-def test_segment_compiles_for_v5e(one_chip, scheme, reset):
+def test_segment_compiles_for_v5e(one_chip, scheme, reset, kcap):
     def spec(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
-    w1, kcap1 = WORKERS + 1, KEY_CAP + 1
-    r_n = 0 if scheme == "sg" else RING_POINTS
-    dmax = {"sg": 0, "pkg": 2}.get(scheme, WORKERS)
-    sig = (scheme, FEED, w1, kcap1, r_n, dmax, True, reset, "assoc")
-    dev, a = _segment_specs(spec, *sig[:6], reset)
+    sig = _sig(scheme, kcap, reset)
+    w1, kcap1, s1 = sig[2], sig[3], sig[-1]
+    dev, a = _segment_specs(spec, *sig[:6], reset, s1)
     ma = feed_fused._get_seg_fn(sig).lower(dev, a).compile(
     ).memory_analysis()
     live = (ma.argument_size_in_bytes + ma.temp_size_in_bytes
             + ma.output_size_in_bytes - ma.alias_size_in_bytes)
     assert live < HBM_BYTES
-    # the per-key tables are donated: a continuing pane updates in place
-    if not reset:
-        assert ma.alias_size_in_bytes >= w1 * kcap1 * 2 * 4
+    # the per-key tables are donated: the replica matrix, and a
+    # continuing pane, update in place
+    assert ma.alias_size_in_bytes >= kcap1 * w1 + (
+        0 if reset else w1 * s1 * 2 * 4)
 
 
-def test_pane_gather_reads_the_segment_layout_for_v5e(one_chip):
+@pytest.mark.parametrize("kcap", KEY_CAPS, ids=("2p20", "2p24"))
+def test_pane_gather_reads_the_segment_layout_for_v5e(one_chip, kcap):
     # the flush gathers from the table the segment wrote: the gather must
     # take it in the layout the segment leaves it in, or the chip copies
     # the whole table to relayout it before every flush
     def spec(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
-    w1, kcap1 = WORKERS + 1, KEY_CAP + 1
-    sig = ("sg", FEED, w1, kcap1, 0, 0, True, True, "assoc")
-    dev, a = _segment_specs(spec, *sig[:6], True)
+    sig = _sig("sg", kcap, True)
+    w1, s1 = sig[2], sig[-1]
+    dev, a = _segment_specs(spec, *sig[:6], True, s1)
     seg = feed_fused._get_seg_fn(sig).lower(dev, a).compile()
     bucket = 4 * FEED
     gather = feed_fused._pane_gather.lower(
-        spec((w1, kcap1, 2), jnp.int32), spec((bucket,), jnp.int32),
+        spec((w1, s1, 2), jnp.int32), spec((bucket,), jnp.int32),
         spec((bucket,), jnp.int32)).compile()
-    table = re.compile(rf"s32\[{w1},{kcap1},2\]\{{[^}}]*\}}")
+    table = re.compile(rf"s32\[{w1},{s1},2\]\{{[^}}]*\}}")
 
     def layouts(exe):
         head = exe.as_text().split("\n", 1)[0]
@@ -131,7 +143,7 @@ def test_pane_gather_reads_the_segment_layout_for_v5e(one_chip):
     assert read == written
     ma = gather.memory_analysis()
     assert ma.output_size_in_bytes == bucket * 2 * 4
-    assert ma.temp_size_in_bytes < w1 * kcap1  # no copy of the table
+    assert ma.temp_size_in_bytes < w1 * s1  # no copy of the table
 
 
 @pytest.mark.parametrize("kernel", ("store_probe", "fish_count",
