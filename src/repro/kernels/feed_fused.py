@@ -10,8 +10,10 @@ a. **routing** — all six schemes as ``jax.numpy`` ops over device state:
    the exact sequential two-choice ``lax.scan``; DC/WC/FISH classify hot
    keys against a device-resident dense frequency tracker (the decayed
    epoch counting of ``kernels/fish_count.py``, here over the per-key
-   table the CHK pass reads) and pick per tuple via a masked-argmin scan
-   (FISH: the Eq. 2 wait-time argmin against the Alg. 3 estimator state);
+   table the CHK pass reads) and pick per tuple: DC/WC via a masked-argmin
+   scan, FISH via one Pallas kernel launch (``kernels/fish_choose.py``)
+   that takes the Eq. 2 wait-time argmin against the Alg. 3 estimator
+   state over lane-dense candidate-rank rows, tuple by tuple;
 b. **FIFO** — the closed-form per-worker recurrence solved on device,
    either as one ``lax.scan`` (exact, the CPU default) or as
    ``jax.lax.associative_scan`` over a segmented maximum-accumulate
@@ -67,6 +69,8 @@ import jax.numpy as jnp
 
 from ..obs.metrics import GLOBAL_METRICS
 from ..obs.telemetry import Telemetry
+from . import fish_choose as _fish_choose
+from . import ops as _ops
 
 
 __all__ = ["FusedEdgeRunner", "fused_reject_reason", "TRACE_COUNT",
@@ -177,6 +181,19 @@ def _build_ring_table(ring, dmax: int):
     return pts, cands
 
 
+def _build_rank_table(cands, workers: int):
+    """(R, L) int32 inverse of the candidate table, for FISH's choice
+    kernel: ``rank[r, cands[r, j]] = j``, :data:`fish_choose.BIG`
+    elsewhere, over ``L`` = the worker universe rounded up to whole
+    128-lane rows.  A ring row holds distinct owners, so each worker has
+    at most one rank per row."""
+    lanes = -(-workers // _fish_choose.LANES) * _fish_choose.LANES
+    rank = np.full((cands.shape[0], lanes), _fish_choose.BIG, np.int32)
+    rr, jj = np.nonzero(cands >= 0)
+    rank[rr, cands[rr, jj]] = jj
+    return rank
+
+
 # ---------------------------------------------------------------------------
 # traced segment bodies
 # ---------------------------------------------------------------------------
@@ -224,24 +241,28 @@ def _fifo_assoc(busy, caps, workers, t):
     return busy.at[ws].max(f), fin
 
 
-def _ring_rows(a, width=None):
-    """(n_pad, width or dmax) candidate rows for this segment's hashed
-    keys.
+def _ring_idx(a):
+    """(n_pad,) ring position of each tuple's hashed key.
 
-    Key→candidates is fixed between membership changes, so when the key
+    Key→position is fixed between membership changes, so when the key
     table is smaller than the segment the ring walk runs once per *key*
-    (over the dense hash cache) and tuples gather their row — ~4× fewer
-    binary-search probes at 16k-tuple segments.  Phantom-row gathers
-    clamp (JAX OOB semantics) and are masked off by ``valid``.  Schemes
-    with a fixed fanout (fg: 1, pkg: 2) pass ``width`` so the per-tuple
-    gather moves ``width`` candidates instead of the full dmax row."""
+    (over the dense hash cache) and tuples gather their key's position —
+    ~4× fewer binary-search probes at 16k-tuple segments.  Phantom-row
+    gathers clamp (JAX OOB semantics) and are masked off by ``valid``."""
     r_n = a["pts"].shape[0]
-    cands = a["cands"] if width is None else a["cands"][:, :width]
     if "hash_arr" in a:
         idx = jnp.searchsorted(a["pts"], a["hash_arr"], side="right") % r_n
-        return cands[idx][a["keys"]]
-    idx = jnp.searchsorted(a["pts"], a["h"], side="right") % r_n
-    return cands[idx]
+        return idx[a["keys"]]
+    return jnp.searchsorted(a["pts"], a["h"], side="right") % r_n
+
+
+def _ring_rows(a, width=None):
+    """(n_pad, width or dmax) candidate rows at the segment's ring
+    positions (``a["ring_idx"]``).  Schemes with a fixed fanout (fg: 1,
+    pkg: 2) pass ``width`` so the per-tuple gather moves ``width``
+    candidates instead of the full dmax row."""
+    cands = a["cands"] if width is None else a["cands"][:, :width]
+    return cands[a["ring_idx"]]
 
 
 def _route_pkg(a, row):
@@ -331,6 +352,27 @@ def _route_dcwc(a, row, scheme):
     return counts, workers, trk
 
 
+def _choose_fish(a, row, d, backlog, assigned):
+    """Alg. 3 Eq. 2 for every tuple of the segment, in stream order, in
+    one :func:`~repro.kernels.fish_choose.fish_choose` launch: each
+    tuple's candidates are a lane-dense row of ranks over the worker
+    lanes (the inverse ring table's row at its ring position, the
+    phantom lane left out), :data:`~repro.kernels.fish_choose.BIG` past
+    its first ``d`` and on padding tuples.  Returns each tuple's worker
+    (the phantom lane on padding) and the estimator's ``assigned`` after
+    the segment."""
+    big = _fish_choose.BIG
+    n_w = backlog.shape[0] - 1
+    rank = a["rank_of"][a["ring_idx"]]
+    rank = jnp.where((rank < d[:, None]) & a["valid"][:, None], rank, big)
+    chosen, asn = _ops.fish_choose(rank, backlog[:n_w], a["ecaps"][:n_w],
+                                   assigned[:n_w])
+    picked = jnp.take_along_axis(
+        row, jnp.minimum(chosen, row.shape[1] - 1)[:, None], axis=1)[:, 0]
+    workers = jnp.where(chosen < big, picked, a["phantom_w"])
+    return workers, assigned.at[:n_w].set(asn)
+
+
 def _route_fish(a, row):
     """FISH: Alg. 1 (dense decayed tracker) + Alg. 2 (CHK with monotone
     memory M_k) + Alg. 3 (per-tuple Eq. 2 wait-time argmin against the
@@ -358,21 +400,7 @@ def _route_fish(a, row):
         backlog = jnp.where(a["do_tick"] > 0, ticked, backlog)
         assigned = jnp.where(a["do_tick"] > 0, 0.0, assigned)
 
-        dmax = row.shape[1]
-        iota_d = jnp.arange(dmax, dtype=jnp.int32)
-        # the scan reads only `asn`; counts never feed the argmin, so they
-        # accumulate in one dense pass after the loop instead of a scatter
-        # per step.
-        def step(asn, x):
-            r, dd, v = x
-            waits = jnp.where((iota_d < dd) & (r >= 0),
-                              (backlog[r] + asn[r]) * a["ecaps"][r], jnp.inf)
-            w = r[jnp.argmin(waits)]
-            w = jnp.where(v, w, a["phantom_w"])
-            return asn.at[w].add(jnp.where(v, 1.0, 0.0)), w
-
-        assigned, workers = jax.lax.scan(
-            step, assigned, (row, d, a["valid"]))
+        workers, assigned = _choose_fish(a, row, d, backlog, assigned)
         lanes = jnp.arange(a["counts"].shape[0], dtype=workers.dtype)
         counts = a["counts"] + jnp.sum(
             (workers[None, :] == lanes[:, None]) & a["valid"][None, :],
@@ -428,6 +456,7 @@ def _get_seg_fn(sig):
                 counts = _count(workers)
         else:
             with jax.named_scope("route/ring"):
+                a["ring_idx"] = _ring_idx(a)
                 row = _ring_rows(a, {"fg": 1, "pkg": 2}.get(scheme))
             if scheme == "fg":
                 with jax.named_scope("route/choose"):
@@ -573,6 +602,9 @@ class FusedEdgeRunner:
             "fused.pane_gathers", scheme=self.scheme)
         self._c_key_regrows = self.tel.metrics.counter(
             "fused.key_regrows", scheme=self.scheme)
+        # tuples FISH's choice kernel routed (every FISH launch's)
+        self._c_choice_tuples = self.tel.metrics.counter(
+            "fused.choice_kernel_tuples", scheme=self.scheme)
         self._feed_base_dispatches = 0
         self._prev_hot = None     # fish hot mask at the last epoch point
         self._fish_epoch_idx = -1
@@ -592,6 +624,7 @@ class FusedEdgeRunner:
         self._cands = None        # ring candidate rows (np int32)
         self._pts_dev = None
         self._cands_dev = None
+        self._rank_dev = None     # FISH: inverse ring table (device)
         self._hash_arr = None     # dense key -> hash32 cache (np uint32)
         self._hash_ok = None
         self._repl_dirty = False
@@ -690,6 +723,9 @@ class FusedEdgeRunner:
             self._pts, self._cands = _build_ring_table(grouper.ring, dmax)
             self._pts_dev = jnp.asarray(self._pts)
             self._cands_dev = jnp.asarray(self._cands)
+        if self.scheme == "fish":
+            self._rank_dev = jnp.asarray(_build_rank_table(
+                self._cands, state.busy_until.shape[0]))
         act = np.asarray(sorted(state.active), dtype=np.int32)
         self._act = act
         self._act_pad = np.full(self._w1, self._w1 - 1, np.int32)
@@ -832,6 +868,7 @@ class FusedEdgeRunner:
             fa = self._fish_args(grouper, lo, hi, state.offset)
             dev["m_k"] = fa.pop("m_k")
             a.update(fa)
+            a["rank_of"] = self._rank_dev
         reset = False
         if self.has_pane:
             vals = np.zeros(n_pad, np.int32)
@@ -858,6 +895,8 @@ class FusedEdgeRunner:
                 launch_span.set(new_signature=True)  # this launch compiles
             out = _get_seg_fn(sig)(dev, a)
         self._c_dispatches.add(1)
+        if scheme == "fish":
+            self._c_choice_tuples.add(m)
 
         # device-resident state stays device-side
         self.repl = out["repl"]

@@ -12,12 +12,14 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from . import fish_choose as _fish_choose
 from . import fish_count as _fish_count
 from . import ssd as _ssd
 from . import store_probe as _store_probe
 from . import ref as ref  # re-exported for tests/benchmarks
 
-__all__ = ["fish_count", "fish_epoch_count", "ssd_scan", "store_probe", "ref"]
+__all__ = ["fish_count", "fish_epoch_count", "fish_choose", "ssd_scan",
+           "store_probe", "ref"]
 
 
 def _interpret() -> bool:
@@ -53,6 +55,22 @@ def fish_epoch_count(table_keys: jnp.ndarray, table_counts: jnp.ndarray,
         interpret=_interpret(),
     )
     return counts[:k], matched, cand, first
+
+
+def fish_choose(rank: jnp.ndarray, backlog: jnp.ndarray, ecaps: jnp.ndarray,
+                assigned: jnp.ndarray):
+    """FISH's sequential least-wait choice over lane-dense candidate
+    ranks ``rank`` (N, L), L a multiple of 128.  The per-worker vectors
+    (backlog, service time, assigned) may be shorter than L: they are
+    padded with workers that are never a candidate.  Returns each
+    tuple's chosen rank (``fish_choose.BIG`` for none) and the updated
+    ``assigned`` at its own length."""
+    nw = assigned.shape[0]
+    pad = rank.shape[1] - nw
+    chosen, asn = _fish_choose.fish_choose(
+        rank, *(jnp.pad(v, (0, pad)) for v in (backlog, ecaps, assigned)),
+        interpret=_interpret())
+    return chosen, asn[:nw]
 
 
 def store_probe(table_keys: jnp.ndarray, batch_keys: jnp.ndarray,

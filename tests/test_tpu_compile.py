@@ -2,9 +2,10 @@
 
 The fused segment function at the benchmark's shapes (129 worker lanes x
 16,384 tuples, associative-scan FIFO, a pane of 65,536 slots) at 2^20+1
-and at 2^24+1 key rows (the 10^7-key ZF cell), the pane flush's gather
-from its table, and the three stream Pallas kernels at K=4096 slots x
-N=16,384 keys.  What the chip's
+and at 2^24+1 key rows (the 10^7-key ZF cell), FISH's with its choice
+kernel compiled as the chip runs it, the pane flush's gather from its
+table, the three stream Pallas kernels at K=4096 slots x N=16,384 keys,
+and FISH's choice kernel at 16,384 tuples x 128 lanes.  What the chip's
 compiler refuses (scoped-VMEM overflow, a program past 16 GB) fails here
 at no chip time.  Nothing runs, so this says nothing about results.
 
@@ -22,7 +23,9 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels import feed_fused
+from repro.kernels import fish_choose as _fish_choose
 from repro.kernels import fish_count as _fish_count
+from repro.kernels import ops as _ops
 from repro.kernels import store_probe as _store_probe
 
 HBM_BYTES = 16 * 10 ** 9  # one v5e chip
@@ -80,7 +83,8 @@ def _segment_specs(spec, scheme, n_pad, w1, kcap1, r_n, dmax, reset, s1):
                  c_total=spec((), f32), d_min=spec((), i32),
                  ebl=spec((w1,), f32), eas=spec((w1,), f32),
                  ecaps=spec((w1,), f32), do_tick=spec((), i32),
-                 elapsed=spec((), f32))
+                 elapsed=spec((), f32),
+                 rank_of=spec((r_n, -(-(w1 - 1) // 128) * 128), i32))
     if not reset:
         dev.update(pane_tab=spec((w1, s1, 2), i32),
                    pane_last=spec((w1,), i32))
@@ -98,15 +102,22 @@ def _sig(scheme, kcap, reset):
 @pytest.mark.parametrize("kcap", KEY_CAPS, ids=("2p20", "2p24"))
 @pytest.mark.parametrize("reset", (True, False), ids=("fresh", "continuing"))
 @pytest.mark.parametrize("scheme", ("sg", "pkg", "fish"))
-def test_segment_compiles_for_v5e(one_chip, scheme, reset, kcap):
+def test_segment_compiles_for_v5e(one_chip, scheme, reset, kcap,
+                                  monkeypatch):
     def spec(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
+    # this process's backend is the CPU, where the Pallas kernels trace
+    # in interpret mode: trace them for the chip, in a fresh cache
+    monkeypatch.setattr(_ops, "_interpret", lambda: False)
+    monkeypatch.setattr(feed_fused, "_SEG_CACHE", {})
     sig = _sig(scheme, kcap, reset)
     w1, kcap1, s1 = sig[2], sig[3], sig[-1]
     dev, a = _segment_specs(spec, *sig[:6], reset, s1)
-    ma = feed_fused._get_seg_fn(sig).lower(dev, a).compile(
-    ).memory_analysis()
+    exe = feed_fused._get_seg_fn(sig).lower(dev, a).compile()
+    # FISH's choice runs as the Pallas kernel inside its segment
+    assert ("tpu_custom_call" in exe.as_text()) == (scheme == "fish")
+    ma = exe.memory_analysis()
     live = (ma.argument_size_in_bytes + ma.temp_size_in_bytes
             + ma.output_size_in_bytes - ma.alias_size_in_bytes)
     assert live < HBM_BYTES
@@ -147,14 +158,18 @@ def test_pane_gather_reads_the_segment_layout_for_v5e(one_chip, kcap):
 
 
 @pytest.mark.parametrize("kernel", ("store_probe", "fish_count",
-                                    "fish_epoch_count"))
+                                    "fish_epoch_count", "fish_choose"))
 def test_stream_kernel_compiles_for_v5e(one_chip, kernel):
     def spec(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
     table = spec((K_SLOTS,), jnp.int32)
     keys = spec((N_KEYS,), jnp.int32)
+    lane = spec((WORKERS,), jnp.float32)
     fn, args = {
+        "fish_choose": (_fish_choose.fish_choose,
+                        (spec((FEED, WORKERS), jnp.int32), lane, lane,
+                         lane)),
         "store_probe": (_store_probe.store_probe, (table, keys, keys)),
         "fish_count": (_fish_count.fish_count, (table, keys)),
         "fish_epoch_count": (
