@@ -38,7 +38,7 @@ def run_scheme(scheme, keys, workers: int, capacities=None,
     if capacities is None:
         capacities = np.full(workers, 0.9 * workers / arrival_rate)
     # no oracle capacities for the grouper: capacity-aware schemes discover
-    # P_w through the sampling hook (matches the legacy make_grouper path)
+    # P_w through the sampling hook
     g = build_grouper(scheme, workers)
     res = simulate_edge(g, keys, mode=simulator, capacities=capacities,
                         arrival_rate=arrival_rate, **kw)

@@ -4,8 +4,8 @@ Layers:
 
 - :mod:`repro.analysis.lint` — AST lint engine with JAX-aware rules
   (host-sync-in-jit, retrace-hazard, np-jnp-mixing, frozen-mutation,
-  deprecated-shim, unordered-iteration, exactness-contract,
-  topology-config, registry-counter-mutation, and the ISSUE-10 rules
+  unordered-iteration, exactness-contract, topology-config,
+  registry-counter-mutation, and the numerics and determinism rules
   int32-overflow / unseeded-rng / wall-clock-leak / unbounded-signature /
   interproc-unordered-iteration);
 - :mod:`repro.analysis.callgraph` — whole-program layer: import

@@ -19,9 +19,6 @@ np-jnp-mixing        ``np.*`` ops or module-level ``np.*`` constants
 frozen-mutation      writes to ``RecordBatch`` columns or frozen-dataclass
                      fields (``object.__setattr__`` outside ``__post_init__``,
                      column element stores, column rebinds).
-deprecated-shim      call sites of ``make_grouper`` / ``simulate_stream`` /
-                     ``simulate_stream_reference`` — runtime
-                     DeprecationWarnings promoted to review-time findings.
 unordered-iteration  ``for``/comprehension iteration directly over set-valued
                      expressions — hash-seed order feeding routing, scatter,
                      or ring mutation order.
@@ -74,7 +71,6 @@ RULES: Tuple[str, ...] = (
     "retrace-hazard",
     "np-jnp-mixing",
     "frozen-mutation",
-    "deprecated-shim",
     "unordered-iteration",
     "exactness-contract",
     "topology-config",
@@ -87,13 +83,6 @@ RULES: Tuple[str, ...] = (
     "unbounded-signature",
     "interproc-unordered-iteration",
 )
-
-_SHIMS = {
-    "make_grouper": "build_grouper(config_for(scheme)) from repro.topology",
-    "simulate_stream": "StreamSession or repro.core.stream.simulate_edge",
-    "simulate_stream_reference":
-        "simulate_edge(..., mode='reference') or a reference StreamSession",
-}
 
 _HOST_SYNC_BUILTINS = {"float", "bool"}
 _HOST_SYNC_METHODS = {"item", "tolist"}
@@ -497,22 +486,6 @@ def _rule_frozen_mutation(mod: ModuleInfo) -> List[Finding]:
     return out
 
 
-def _rule_deprecated_shim(mod: ModuleInfo) -> List[Finding]:
-    out = []
-    for node in ast.walk(mod.tree):
-        if not isinstance(node, ast.Call):
-            continue
-        name = _call_name(node)
-        if name in _SHIMS and name not in mod.funcs:
-            out.append(mod.finding(
-                "deprecated-shim", node, "error",
-                f"call to deprecated shim `{name}` (a runtime "
-                f"DeprecationWarning, promoted to error by pyproject "
-                f"filterwarnings)",
-                f"use {_SHIMS[name]}"))
-    return out
-
-
 class _SetTracker(ast.NodeVisitor):
     """Track local names bound to set-valued expressions, per scope."""
 
@@ -834,7 +807,6 @@ _RULE_FNS = {
     "retrace-hazard": _rule_retrace_hazard,
     "np-jnp-mixing": _rule_np_jnp_mixing,
     "frozen-mutation": _rule_frozen_mutation,
-    "deprecated-shim": _rule_deprecated_shim,
     "unordered-iteration": _rule_unordered_iteration,
     "exactness-contract": _rule_exactness_contract,
     "topology-config": _rule_topology_config,

@@ -319,6 +319,6 @@ def rule_int32_overflow(mod) -> List[Finding]:
             f"it wraps 2³¹−1 once the mean per-step increment reaches "
             f"{min_inc}",
             "hold the running total in int64 (a device kernel can keep "
-            "its int32 chunk domain and widen at the fold — see "
-            "DeviceStateStore's int64 lifetime base)"))
+            "its int32 chunk domain and widen into an int64 host total "
+            "at the fold)"))
     return out

@@ -10,18 +10,9 @@ from .baselines import (
     PartialKeyGrouping,
     ShuffleGrouping,
     WChoices,
-    make_grouper,
 )
 from .chash import ConsistentHashRing, hash32
-from .fish import (
-    EpochFrequencyTracker,
-    FishParams,
-    FishState,
-    chk_num_workers,
-    classify_hot_keys,
-    epoch_update,
-    init_fish_state,
-)
+from .fish import EpochFrequencyTracker, FishParams, chk_num_workers
 from .stream import (
     CapacityEvent,
     EdgeResult,
@@ -31,8 +22,6 @@ from .stream import (
     at_time,
     edge_metrics,
     simulate_edge,
-    simulate_stream,
-    simulate_stream_reference,
 )
 
 __all__ = [
@@ -45,16 +34,11 @@ __all__ = [
     "PartialKeyGrouping",
     "ShuffleGrouping",
     "WChoices",
-    "make_grouper",
     "ConsistentHashRing",
     "hash32",
     "EpochFrequencyTracker",
     "FishParams",
-    "FishState",
     "chk_num_workers",
-    "classify_hot_keys",
-    "epoch_update",
-    "init_fish_state",
     "CapacityEvent",
     "EdgeResult",
     "EdgeState",
@@ -63,6 +47,4 @@ __all__ = [
     "at_time",
     "edge_metrics",
     "simulate_edge",
-    "simulate_stream",
-    "simulate_stream_reference",
 ]

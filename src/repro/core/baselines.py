@@ -51,7 +51,6 @@ __all__ = [
     "DChoices",
     "WChoices",
     "FishGrouper",
-    "make_grouper",
 ]
 
 
@@ -626,24 +625,3 @@ class FishGrouper(Grouper):
 
     def _membership_caches_clear(self) -> None:
         self._mod_cands.clear()
-
-
-def make_grouper(name: str, num_workers: int, **kwargs) -> Grouper:
-    """Deprecated stringly-typed factory — a thin shim over the typed-config
-    registry in :mod:`repro.topology.configs`.
-
-    New code uses one config per scheme (``FishConfig(...).build(w)``) or
-    :func:`repro.topology.configs.build_grouper`; this shim keeps legacy
-    ``make_grouper(name, **kwargs)`` call sites working unchanged.
-    """
-    import warnings
-
-    warnings.warn(
-        "make_grouper is deprecated; use the typed scheme configs in "
-        "repro.topology.configs (e.g. FishConfig().build(num_workers)) or "
-        "repro.topology.configs.build_grouper",
-        DeprecationWarning, stacklevel=2,
-    )
-    from ..topology.configs import legacy_build
-
-    return legacy_build(name, num_workers, **kwargs)

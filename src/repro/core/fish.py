@@ -1,18 +1,12 @@
 """FISH epoch-based recent hot-key identification (paper Alg. 1 + Alg. 2).
 
-Two implementations live here:
-
-* :class:`EpochFrequencyTracker` — the paper-faithful *sequential* host-side
-  implementation: per-tuple SpaceSaving with replace-min (count inherited from
-  the evicted minimum, Alg. 1 lines 19-22) and per-epoch time decay
-  (``TimeDecayingUpdate``, lines 23-26).  This is what the reproduction
-  benchmarks use.
-* :func:`epoch_update` / :func:`classify_hot_keys` — branch-free ``jax.lax``
-  versions for the device-side fast path (MoE routing).  The match-and-count
-  hotspot is the Pallas kernel in :mod:`repro.kernels.fish_count`; here we keep
-  a pure-jnp fallback with the same semantics (epoch-batched ReplaceMin — see
-  DESIGN.md §4 for the fidelity note and tests for the Jaccard bound vs. the
-  sequential oracle).
+:class:`EpochFrequencyTracker` is the paper-faithful *sequential* host-side
+implementation: per-tuple SpaceSaving with replace-min (count inherited from
+the evicted minimum, Alg. 1 lines 19-22) and per-epoch time decay
+(``TimeDecayingUpdate``, lines 23-26).  The host engines and the
+reproduction benchmarks use it; the fused device path keeps its own dense
+per-key tracker (:mod:`repro.kernels.feed_fused`).  :func:`chk_num_workers`
+and :func:`chk_num_workers_batch` are CHK (Alg. 2).
 """
 
 from __future__ import annotations
@@ -22,17 +16,11 @@ import heapq
 import math
 from typing import Dict, List, Optional, Sequence, Tuple
 
-import jax
-import jax.numpy as jnp
 import numpy as np
 
 __all__ = [
     "FishParams",
     "EpochFrequencyTracker",
-    "FishState",
-    "init_fish_state",
-    "epoch_update",
-    "classify_hot_keys",
     "chk_num_workers",
     "chk_num_workers_batch",
 ]
@@ -116,10 +104,9 @@ class EpochFrequencyTracker:
         """Bulk Alg. 1 over epoch-aligned chunks (ISSUE 1 tentpole).
 
         Instead of one Python call per tuple, each epoch-sized chunk is one
-        ``np.unique`` count plus a single batched ReplaceMin — the host mirror
-        of :func:`epoch_update`.  Exact while the table is under capacity;
-        at capacity it is the same epoch-batched approximation the device
-        path uses (bounded divergence, see DESIGN.md §4/§6).
+        ``np.unique`` count plus a single batched ReplaceMin.  Exact while
+        the table is under capacity; at capacity it is an epoch-batched
+        approximation (bounded divergence, see DESIGN.md §4/§6).
         """
         arr = np.asarray(keys)
         if arr.ndim != 1 or arr.dtype.kind not in "iu":
@@ -272,163 +259,3 @@ def chk_num_workers_batch(
     new_m_k = np.where(hot, np.maximum(m_k, d), m_k)
     d = np.where(hot, np.maximum(d, m_k), 2)
     return d, new_m_k
-
-
-# ---------------------------------------------------------------------------
-# Device-side state + epoch-batched update (jax.lax, jit-able)
-# ---------------------------------------------------------------------------
-
-
-class FishState(dict):
-    """Pytree: bounded counter table on device.
-
-    keys:   (k_max,) int32   — key ids, -1 for empty slots
-    counts: (k_max,) float32 — decayed occurrence counters
-    """
-
-    def __init__(self, keys, counts):
-        super().__init__(keys=keys, counts=counts)
-
-    @property
-    def keys_arr(self):
-        return self["keys"]
-
-    @property
-    def counts_arr(self):
-        return self["counts"]
-
-
-def init_fish_state(k_max: int) -> FishState:
-    return FishState(
-        keys=jnp.full((k_max,), -1, dtype=jnp.int32),
-        counts=jnp.zeros((k_max,), dtype=jnp.float32),
-    )
-
-
-def _match_counts(table_keys: jnp.ndarray, batch_keys: jnp.ndarray):
-    """Pure-jnp fallback of the fish_count kernel: one-hot match & count.
-
-    Returns (counts_delta (k_max,), matched (n,) bool).
-    """
-    eq = (batch_keys[:, None] == table_keys[None, :]) & (table_keys[None, :] >= 0)
-    counts_delta = jnp.sum(eq, axis=0).astype(jnp.float32)
-    matched = jnp.any(eq, axis=1)
-    return counts_delta, matched
-
-
-def epoch_update(
-    state: FishState,
-    batch_keys: jnp.ndarray,
-    *,
-    alpha: float,
-    max_new: int = 64,
-    match_fn=None,
-    fused_fn=None,
-) -> FishState:
-    """Process one epoch of keys through the bounded counter table.
-
-    Device-side analog of Alg. 1 with epoch-batched ReplaceMin:
-
-    1. inter-epoch decay:   counts *= alpha
-    2. intra-epoch counting: counts[k] += #occurrences for keys already in K
-       (the O(N·K_max) hotspot — ``match_fn`` defaults to the pure-jnp oracle;
-       the Pallas kernel from kernels/ops.py can be passed instead)
-    3. batched ReplaceMin: the ``max_new`` most frequent *unmatched* keys of
-       this epoch are merged via a vectorised sort-based merge — the bottom
-       ``max_new`` counters (ascending) are paired against the top ``max_new``
-       candidates (descending); each inserted key inherits ``c_min + its
-       epoch frequency`` (Alg. 1 line 22 generalised to a batch).
-
-    ``fused_fn``, when given, is the single-launch Pallas path
-    (``repro.kernels.ops.fish_epoch_count``): one kernel yields the decayed
-    counts + epoch delta, the match flags, and the unmatched-candidate epoch
-    histogram, replacing steps 1-2 *and* the sort/segment candidate pass.
-
-    ``batch_keys``: (n,) int32 key ids (>= 0).  Static shapes throughout.
-    """
-    table_keys = state["keys"]
-    n = batch_keys.shape[0]
-    # top_k cannot take k larger than its operand; a partial final epoch may
-    # carry fewer tuples than max_new, and more than k_max inserts per epoch
-    # can never land anyway
-    max_new = min(max_new, int(table_keys.shape[0]), n)
-
-    if fused_fn is not None:
-        # fused: decay + match-count + candidate histogram in one launch
-        counts, matched, cand_count, is_first = fused_fn(
-            table_keys, state["counts"], batch_keys, alpha=alpha
-        )
-        scores = jnp.where(is_first & ~matched, cand_count, 0.0)
-        top_len, top_idx = jax.lax.top_k(scores, max_new)
-        top_key = batch_keys[top_idx]
-    else:
-        if match_fn is None:
-            match_fn = _match_counts
-        counts = state["counts"] * jnp.float32(alpha)  # TimeDecayingUpdate
-        counts_delta, matched = match_fn(table_keys, batch_keys)
-        counts = counts + counts_delta
-
-        # --- candidate new keys: epoch frequency of unmatched keys ----------
-        # Sort unmatched keys so identical ids are adjacent, then
-        # segment-count.
-        cand_keys = jnp.where(matched, jnp.int32(-1), batch_keys)
-        sorted_keys = jnp.sort(cand_keys)
-        new_run = jnp.concatenate(
-            [jnp.ones((1,), bool), sorted_keys[1:] != sorted_keys[:-1]]
-        )
-        run_id = jnp.cumsum(new_run.astype(jnp.int32)) - 1
-        run_len = jax.ops.segment_sum(
-            jnp.ones((n,), jnp.float32), run_id, num_segments=n
-        )
-        run_key = jax.ops.segment_max(sorted_keys, run_id, num_segments=n)
-        run_len = jnp.where(run_key >= 0, run_len, 0.0)  # drop matched/-1 run
-
-        # top `max_new` candidate keys by epoch frequency
-        top_len, top_idx = jax.lax.top_k(run_len, max_new)
-        top_key = run_key[top_idx]
-
-    # --- batched ReplaceMin: vectorised sort-based merge ---------------------
-    # (replaces the former O(max_new · k_max) lax.scan — ISSUE 1 tentpole)
-    empty = table_keys < 0
-    eff = jnp.where(empty, 0.0, counts)  # empty slots are free minima
-    bottom = jnp.argsort(eff)[:max_new]  # slots ascending by counter
-    do = top_len > 0.0
-    merged_counts = eff[bottom] + top_len
-    table_keys = table_keys.at[bottom].set(
-        jnp.where(do, top_key, table_keys[bottom])
-    )
-    counts = counts.at[bottom].set(
-        jnp.where(do, merged_counts, counts[bottom])
-    )
-    return FishState(keys=table_keys, counts=counts)
-
-
-def classify_hot_keys(
-    state: FishState,
-    *,
-    num_workers: int,
-    theta: float,
-    d_min: int = 2,
-    m_k: Optional[jnp.ndarray] = None,
-) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
-    """Vectorised CHK (Alg. 2) over the whole table.
-
-    Returns ``(d, is_hot, new_m_k)`` — per-slot candidate-worker counts
-    (non-hot slots get 2), hotness mask, and the updated monotone memory.
-    """
-    counts = state["counts"]
-    total = jnp.maximum(jnp.sum(counts), 1e-30)
-    f = counts / total
-    f_top = jnp.max(f)
-    is_hot = f > theta
-    ratio = jnp.maximum(f_top / jnp.maximum(f, 1e-30), 1.0)
-    index = jnp.floor(jnp.log2(ratio)).astype(jnp.int32)
-    index = jnp.clip(index, 0, 30)
-    d = (num_workers // (2**index)).astype(jnp.int32)
-    d = jnp.maximum(d, d_min)
-    d = jnp.minimum(d, num_workers)
-    if m_k is None:
-        m_k = jnp.zeros_like(d)
-    new_m_k = jnp.where(is_hot, jnp.maximum(m_k, d), m_k)
-    d = jnp.where(is_hot, jnp.maximum(d, m_k), 2)
-    return d, is_hot, new_m_k

@@ -29,10 +29,6 @@ next) and returning per-tuple finish times alongside the metrics.
   the batched-vs-reference equivalence tests (exact for SG/FG/PKG, bounded
   drift for DC/WC/FISH — see DESIGN.md §6).
 
-:func:`simulate_stream` / :func:`simulate_stream_reference` remain as
-deprecated single-hop shims over :func:`simulate_edge`; new code goes
-through :mod:`repro.topology` (ISSUE 3 — one engine protocol).
-
 Incremental (sessioned) execution — ISSUE 5: :func:`simulate_edge` accepts a
 carried :class:`EdgeState` (per-worker ``busy_until``, mutated capacities,
 active set, sampling rng, global tuple offset) so a topology session can cut
@@ -72,8 +68,6 @@ __all__ = [
     "at_time",
     "edge_metrics",
     "simulate_edge",
-    "simulate_stream",
-    "simulate_stream_reference",
 ]
 
 
@@ -803,33 +797,3 @@ def _edge_reference(grouper, keys, times, capacities, arrival_rate,
     metrics = (edge_metrics(grouper, busy_until, latencies, n)
                if compute_metrics else None)
     return EdgeResult(metrics, finishes, latencies, state)
-
-
-def _warn_legacy(name: str) -> None:
-    warnings.warn(
-        f"{name} is deprecated; build a Topology and run it through "
-        "repro.topology (SimulatorEngine / ServingTopologyEngine), or call "
-        "repro.core.simulate_edge for a single grouped edge",
-        DeprecationWarning, stacklevel=3,
-    )
-
-
-def simulate_stream(grouper: Grouper, keys: Sequence, **kwargs
-                    ) -> StreamMetrics:
-    """Deprecated single-hop shim: the batched engine on a uniform source.
-
-    Kept so legacy call sites keep working; new code builds a
-    :class:`repro.topology.Topology` and runs it through an engine, or calls
-    :func:`simulate_edge` directly.  Accepts the same keyword arguments as
-    :func:`simulate_edge` (minus ``times``/``mode``).
-    """
-    _warn_legacy("simulate_stream")
-    return simulate_edge(grouper, keys, mode="batched", **kwargs).metrics
-
-
-def simulate_stream_reference(grouper: Grouper, keys: Sequence, **kwargs
-                              ) -> StreamMetrics:
-    """Deprecated single-hop shim: the per-tuple oracle on a uniform source
-    (see :func:`simulate_stream`)."""
-    _warn_legacy("simulate_stream_reference")
-    return simulate_edge(grouper, keys, mode="reference", **kwargs).metrics

@@ -1,3 +1,2 @@
-# OPTIONAL layer. Add <name>.py (or .cu) + ops.py + ref.py ONLY
-# for compute hot-spots the paper itself optimizes with a custom
-# kernel. Leave this package empty if the paper has none.
+"""The fused segment program, FISH's choice kernel and their wrappers;
+``ssd`` is the language-model substrate's scan kernel."""

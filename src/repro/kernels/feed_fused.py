@@ -9,11 +9,11 @@ a. **routing** — all six schemes as ``jax.numpy`` ops over device state:
    the ring points — the device mirror of ``chash.lookup_n``); PKG runs
    the exact sequential two-choice ``lax.scan``; DC/WC/FISH classify hot
    keys against a device-resident dense frequency tracker (the decayed
-   epoch counting of ``kernels/fish_count.py``, here over the per-key
-   table the CHK pass reads) and pick per tuple: DC/WC via a masked-argmin
-   scan, FISH via one Pallas kernel launch (``kernels/fish_choose.py``)
-   that takes the Eq. 2 wait-time argmin against the Alg. 3 estimator
-   state over lane-dense candidate-rank rows, tuple by tuple;
+   epoch counting of Alg. 1, over the per-key table the CHK pass reads)
+   and pick per tuple: DC/WC via a masked-argmin scan, FISH via one
+   Pallas kernel launch (``kernels/fish_choose.py``) that takes the Eq. 2
+   wait-time argmin against the Alg. 3 estimator state over lane-dense
+   candidate-rank rows, tuple by tuple;
 b. **FIFO** — the closed-form per-worker recurrence solved on device,
    either as one ``lax.scan`` (exact, the CPU default) or as
    ``jax.lax.associative_scan`` over a segmented maximum-accumulate
@@ -24,9 +24,7 @@ c. **keyed-state update** — per-(worker, pane slot) aggregate tables
    follows the pane's tuples, not the key space); panes sync to the host
    :class:`~repro.state.window.KeyedStateManager` only at pane boundaries
    and membership events (``merge_entries`` accumulates, so a pane can be
-   synced mid-way and continue on zeroed device tables exactly).  The
-   standalone probe/accumulate kernel behind the ``"device"`` store
-   backend lives in :mod:`repro.kernels.store_probe`.
+   synced mid-way and continue on zeroed device tables exactly).
 
 A steady-state ``session.feed(batch)`` is therefore **one** device
 dispatch (counted in :attr:`FusedEdgeRunner.dispatches`, surfaced as

@@ -1,34 +1,11 @@
-"""Pure-jnp oracles for every Pallas kernel (exact, unblocked)."""
+"""Pure-jnp oracles for the SSD kernels (exact, unblocked)."""
 
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
 
-__all__ = ["fish_count_ref", "fish_epoch_count_ref", "ssd_ref",
-           "ssd_chunked_ref"]
-
-
-def fish_count_ref(table_keys: jnp.ndarray, batch_keys: jnp.ndarray):
-    """Oracle for kernels.fish_count: full equality matrix."""
-    eq = (batch_keys[:, None] == table_keys[None, :]) & (table_keys[None, :] >= 0)
-    counts = jnp.sum(eq, axis=0).astype(jnp.float32)
-    matched = jnp.any(eq, axis=1)
-    return counts, matched
-
-
-def fish_epoch_count_ref(table_keys: jnp.ndarray, table_counts: jnp.ndarray,
-                         batch_keys: jnp.ndarray, *, alpha: float):
-    """Oracle for kernels.fish_epoch_count: decay + match + histogram,
-    all as full equality matrices."""
-    delta, matched = fish_count_ref(table_keys, batch_keys)
-    new_counts = table_counts.astype(jnp.float32) * jnp.float32(alpha) + delta
-    self_eq = batch_keys[:, None] == batch_keys[None, :]
-    cand = jnp.sum(self_eq, axis=1).astype(jnp.float32)
-    n = batch_keys.shape[0]
-    col = jnp.arange(n)[None, :]
-    first = jnp.sum(self_eq & (col < jnp.arange(n)[:, None]), axis=1) == 0
-    return new_counts, matched, cand, first
+__all__ = ["ssd_ref", "ssd_chunked_ref"]
 
 
 def ssd_ref(x, a, b, c, initial_state=None):

@@ -36,7 +36,6 @@ __all__ = [
     "ENTRY_BYTES",
     "DictStateStore",
     "ArrayStateStore",
-    "DeviceStateStore",
     "STORE_BACKENDS",
     "make_store",
 ]
@@ -321,178 +320,7 @@ class ArrayStateStore:
         return ks[order], self._v[live].copy(), self._c[live].copy()
 
 
-class DeviceStateStore:
-    """Device-resident backend (ISSUE 6): the sorted slot table and int32
-    (value, count) accumulators live as jax device arrays, and folding a
-    reduced chunk is one probe/accumulate launch per column
-    (:func:`repro.kernels.ops.store_probe` — the Pallas kernel on TPU, a
-    ``searchsorted`` fallback elsewhere; two launches because the kernel
-    accumulates one value column at a time).  A sorted host int64 key
-    mirror keeps membership checks, sizing and ``items`` ordering
-    off-device; inserting unseen keys rebuilds the device table around
-    them (the open-addressing slow path — rare once the key set is warm).
-
-    Accumulation is generational (ISSUE 10): the device arrays are an
-    int32 *young generation* — the kernel's probe/accumulate domain, with
-    inputs range-checked per merge — and a host int64 *lifetime base*
-    (``_base_v``/``_base_c``) carries totals beyond int32.  A conservative
-    running bound on the young generation's magnitude (the sum of per-merge
-    chunk bounds) triggers a spill — read the young columns back, add into
-    the base, zero the device arrays — strictly before any element could
-    reach 2³¹−1, so lifetime aggregates are exact at the ROADMAP's
-    10⁸-tuple scale (``repro.analysis.contracts.SCALE_TARGET``) without
-    enabling x64 on device.  ``items``/``take`` return base + young."""
-
-    backend = "device"
-
-    def __init__(self) -> None:
-        self._host_keys = np.empty(0, dtype=np.int64)  # sorted mirror
-        self._keys = None  # device int32, sorted ascending (lazy)
-        self._v = None     # device int32 young-gen value accumulators
-        self._c = None     # device int32 young-gen count accumulators
-        self._base_v = np.empty(0, dtype=np.int64)  # host lifetime base
-        self._base_c = np.empty(0, dtype=np.int64)
-        self._young_bound = 0  # ≥ max |young element|, per-merge accumulated
-
-    # -- interface ------------------------------------------------------------
-    @property
-    def num_entries(self) -> int:
-        return int(self._host_keys.shape[0])
-
-    def size_bytes(self) -> int:
-        return int(self._host_keys.shape[0]) * ENTRY_BYTES
-
-    def update_batch(self, keys: np.ndarray, values: np.ndarray) -> None:
-        uniq, inv = np.unique(np.asarray(keys, dtype=np.int64),
-                              return_inverse=True)
-        vsum = np.zeros(uniq.shape[0], dtype=np.int64)
-        np.add.at(vsum, inv, np.asarray(values, dtype=np.int64))
-        csum = np.bincount(inv, minlength=uniq.shape[0]).astype(np.int64)
-        self._merge(uniq, vsum, csum)
-
-    def merge_entries(self, keys: np.ndarray, values: np.ndarray,
-                      counts: np.ndarray, own: bool = False) -> None:
-        self._merge(np.asarray(keys, dtype=np.int64),
-                    np.asarray(values, dtype=np.int64),
-                    np.asarray(counts, dtype=np.int64))
-
-    def _merge(self, uniq: np.ndarray, vsum: np.ndarray,
-               csum: np.ndarray) -> None:
-        """Fold per-key reduced (value, count) columns into the device
-        table.  ``uniq`` must be sorted unique (both callers guarantee
-        it)."""
-        import jax.numpy as jnp
-
-        from ..kernels import ops
-
-        n = uniq.shape[0]
-        if n == 0:
-            return
-        lim = 2 ** 31 - 1
-        if uniq[0] < 0 or uniq[-1] > lim:
-            raise ValueError(
-                "DeviceStateStore keys must fit int32 (got range "
-                f"[{uniq[0]}, {uniq[-1]}])")
-        chunk_bound = int(max(np.abs(vsum).max(initial=0),
-                              np.abs(csum).max(initial=0)))
-        if chunk_bound > lim:
-            raise ValueError(
-                "DeviceStateStore accumulates in int32; chunk aggregates "
-                "exceed its range")
-        # spill young → base before this chunk could push any young
-        # element past int32 (each merge adds ≤ chunk_bound per element)
-        if self._young_bound + chunk_bound > lim:
-            self._spill()
-        pos = np.searchsorted(self._host_keys, uniq)
-        k = self._host_keys.shape[0]
-        posc = np.clip(pos, 0, max(k - 1, 0))
-        present = ((pos < k) & (self._host_keys[posc] == uniq)) if k else (
-            np.zeros(n, dtype=bool))
-        missing = uniq[~present]
-        if missing.shape[0]:
-            union = np.sort(np.concatenate([self._host_keys, missing]))
-            nv = jnp.zeros(union.shape[0], jnp.int32)
-            nc = jnp.zeros(union.shape[0], jnp.int32)
-            nbv = np.zeros(union.shape[0], dtype=np.int64)
-            nbc = np.zeros(union.shape[0], dtype=np.int64)
-            if k:
-                old_pos = np.searchsorted(union, self._host_keys)
-                nv = nv.at[jnp.asarray(old_pos)].set(self._v)
-                nc = nc.at[jnp.asarray(old_pos)].set(self._c)
-                nbv[old_pos] = self._base_v
-                nbc[old_pos] = self._base_c
-            self._host_keys = union
-            self._keys = jnp.asarray(union.astype(np.int32))
-            self._v = nv
-            self._c = nc
-            self._base_v = nbv
-            self._base_c = nbc
-        keys32 = jnp.asarray(uniq.astype(np.int32))
-        vacc, _, _ = ops.store_probe(self._keys, keys32,
-                                     jnp.asarray(vsum.astype(np.int32)))
-        cacc, _, _ = ops.store_probe(self._keys, keys32,
-                                     jnp.asarray(csum.astype(np.int32)))
-        # int32-overflow(baselined): young-gen adds are bounded by the
-        # _young_bound spill guard above — lifetime totals live in the
-        # int64 base
-        self._v = self._v + vacc
-        self._c = self._c + cacc
-        self._young_bound += chunk_bound
-
-    def _spill(self) -> None:
-        """Fold the int32 young generation into the int64 lifetime base
-        and zero the device accumulators (one readback; amortized over
-        ~2³¹/chunk_bound merges)."""
-        import jax.numpy as jnp
-
-        if self._v is not None and self._host_keys.shape[0]:
-            self._base_v = self._base_v + np.asarray(self._v,
-                                                     dtype=np.int64)
-            self._base_c = self._base_c + np.asarray(self._c,
-                                                     dtype=np.int64)
-            self._v = jnp.zeros_like(self._v)
-            self._c = jnp.zeros_like(self._c)
-        self._young_bound = 0
-
-    def take(self, keys: np.ndarray) -> Tuple[np.ndarray, np.ndarray]:
-        import jax.numpy as jnp
-
-        keys = np.asarray(keys, dtype=np.int64)
-        if keys.shape[0] == 0:
-            return (np.empty(0, dtype=np.int64), np.empty(0, dtype=np.int64))
-        k = self._host_keys.shape[0]
-        pos = np.searchsorted(self._host_keys, keys)
-        posc = np.clip(pos, 0, max(k - 1, 0))
-        ok = ((pos < k) & (self._host_keys[posc] == keys)) if k else (
-            np.zeros(keys.shape[0], dtype=bool))
-        if not ok.all():
-            raise KeyError(
-                f"{int((~ok).sum())} keys absent from DeviceStateStore")
-        v = np.asarray(self._v, dtype=np.int64)
-        c = np.asarray(self._c, dtype=np.int64)
-        vals = (self._base_v[pos] + v[pos]).copy()
-        cnts = (self._base_c[pos] + c[pos]).copy()
-        keep = np.ones(k, dtype=bool)
-        keep[pos] = False
-        self._host_keys = self._host_keys[keep]
-        self._keys = jnp.asarray(self._host_keys.astype(np.int32))
-        self._v = jnp.asarray(v[keep].astype(np.int32))
-        self._c = jnp.asarray(c[keep].astype(np.int32))
-        self._base_v = self._base_v[keep]
-        self._base_c = self._base_c[keep]
-        return vals, cnts
-
-    def items(self) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
-        if self._host_keys.shape[0] == 0:
-            return (np.empty(0, dtype=np.int64), np.empty(0, dtype=np.int64),
-                    np.empty(0, dtype=np.int64))
-        return (self._host_keys.copy(),
-                self._base_v + np.asarray(self._v, dtype=np.int64),
-                self._base_c + np.asarray(self._c, dtype=np.int64))
-
-
-STORE_BACKENDS = {"dict": DictStateStore, "array": ArrayStateStore,
-                  "device": DeviceStateStore}
+STORE_BACKENDS = {"dict": DictStateStore, "array": ArrayStateStore}
 
 
 def make_store(backend: str):

@@ -4,14 +4,11 @@ One frozen dataclass per scheme (paper §2.2 baselines + FISH), each with
 eager validation and a ``build(num_workers)`` method that constructs the
 matching :class:`~repro.core.baselines.Grouper`.  An :class:`Edge` in a
 :class:`~repro.topology.graph.Topology` carries one of these configs, so a
-whole dataflow DAG is a plain, hashable, printable value — no stringly-typed
-``make_grouper(name, **kwargs)`` plumbing.
+whole dataflow DAG is a plain, hashable, printable value.
 
-The registry here is the single source of truth for scheme names.  The
-legacy ``repro.core.baselines.make_grouper`` entry point is a shim over
-:func:`legacy_build` and emits a :class:`DeprecationWarning`; internal code
-uses :func:`build_grouper` (accepts a name or a config) or the configs
-directly.
+The registry here is the single source of truth for scheme names; code
+builds groupers through :func:`build_grouper` (accepts a name or a config)
+or the configs directly.
 """
 
 from __future__ import annotations
@@ -36,7 +33,6 @@ __all__ = [
     "SCHEME_CONFIGS",
     "config_for",
     "build_grouper",
-    "legacy_build",
 ]
 
 
@@ -212,16 +208,6 @@ SCHEME_CONFIGS: Dict[str, Type[SchemeConfig]] = {
                           DChoicesConfig, WChoicesConfig, FishConfig)
 }
 
-# grouper classes keyed by scheme name — the legacy **kwargs constructor path
-_GROUPER_CLASSES: Dict[str, Type[Grouper]] = {
-    "sg": ShuffleGrouping,
-    "fg": FieldGrouping,
-    "pkg": PartialKeyGrouping,
-    "dc": DChoices,
-    "wc": WChoices,
-    "fish": FishGrouper,
-}
-
 
 def config_for(scheme: str, **overrides) -> SchemeConfig:
     """Default typed config for ``scheme``, with field overrides."""
@@ -237,8 +223,7 @@ def build_grouper(spec, num_workers: int,
                   capacities: Optional[np.ndarray] = None) -> Grouper:
     """Build a grouper from a :class:`SchemeConfig` or a scheme name.
 
-    The non-deprecated internal entry point: string specs resolve to the
-    default config for that scheme.
+    String specs resolve to the default config for that scheme.
     """
     if isinstance(spec, SchemeConfig):
         return spec.build(num_workers, capacities=capacities)
@@ -246,14 +231,3 @@ def build_grouper(spec, num_workers: int,
         return config_for(spec).build(num_workers, capacities=capacities)
     raise TypeError(f"grouping spec must be a SchemeConfig or scheme name, "
                     f"got {type(spec).__name__}")
-
-
-def legacy_build(name: str, num_workers: int, **kwargs) -> Grouper:
-    """Construct a grouper class directly with legacy ``**kwargs`` — the
-    implementation behind the deprecated ``make_grouper`` shim."""
-    try:
-        cls = _GROUPER_CLASSES[name.lower()]
-    except KeyError:
-        raise ValueError(f"unknown grouping scheme {name!r}; one of "
-                         f"{sorted(_GROUPER_CLASSES)}")
-    return cls(num_workers, **kwargs)

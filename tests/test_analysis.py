@@ -38,9 +38,6 @@ GOLDEN = {
         "frozen-mutation": {(11, "note"), (14, "error"), (18, "error"),
                             (19, "error"), (20, "error")},
     },
-    "shim_fixture.py": {
-        "deprecated-shim": {(7, "error"), (8, "error")},
-    },
     "unordered_fixture.py": {
         "unordered-iteration": {(7, "warn"), (9, "warn"), (10, "warn")},
     },
@@ -202,9 +199,9 @@ def test_baseline_fingerprint_survives_line_drift():
 
 
 def test_baseline_reports_stale_entries():
-    b = Baseline({"deprecated-shim::src/gone.py::f": (1, "was justified")})
+    b = Baseline({"unseeded-rng::src/gone.py::f": (1, "was justified")})
     fresh, stale = apply_baseline([], b)
-    assert fresh == [] and stale == ["deprecated-shim::src/gone.py::f"]
+    assert fresh == [] and stale == ["unseeded-rng::src/gone.py::f"]
 
 
 def test_baseline_requires_why(tmp_path):
@@ -372,8 +369,8 @@ def test_auditor_rejects_unknown_sync_context():
 def _write_violation(tmp_path: Path) -> Path:
     bad = tmp_path / "injected.py"
     bad.write_text(
-        "from repro.core import make_grouper\n"
-        "g = make_grouper('pkg', 4)\n")
+        "import numpy as np\n"
+        "x = np.random.rand(4)\n")
     return bad
 
 
@@ -382,7 +379,7 @@ def test_cli_red_on_injected_violation(tmp_path, capsys):
     rc = analysis_main([str(bad), "--no-baseline"])
     out = capsys.readouterr().out
     assert rc == 1
-    assert "deprecated-shim" in out and "injected.py:2" in out
+    assert "unseeded-rng" in out and "injected.py:2" in out
 
 
 def test_cli_green_on_clean_file(tmp_path):
@@ -399,11 +396,11 @@ def test_cli_baseline_cycle(tmp_path, capsys):
     assert data["accepted"][0]["why"].startswith("TODO")
     # an unjustified baseline is rejected outright
     assert analysis_main([str(bad), "--baseline", str(base)]) == 1
-    data["accepted"][0]["why"] = "intentional shim-compat test double"
+    data["accepted"][0]["why"] = "intentional global-RNG test double"
     base.write_text(json.dumps(data))
     assert analysis_main([str(bad), "--baseline", str(base)]) == 0
     # a second instance of the same fingerprint is new again
-    bad.write_text(bad.read_text() + "h = make_grouper('pkg', 8)\n")
+    bad.write_text(bad.read_text() + "y = np.random.rand(8)\n")
     assert analysis_main([str(bad), "--baseline", str(base)]) == 1
 
 
@@ -416,7 +413,7 @@ def test_cli_json_artifact(tmp_path):
     data = json.loads(report.read_text())
     assert data["new"] == data["total"] == 1
     (entry,) = data["findings"]
-    assert entry["rule"] == "deprecated-shim" and entry["new"]
+    assert entry["rule"] == "unseeded-rng" and entry["new"]
 
 
 def test_cli_usage_errors(tmp_path):

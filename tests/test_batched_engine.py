@@ -9,14 +9,10 @@ Contract (DESIGN.md §6):
   granularity and Alg. 3 is water-filled per unique key, so individual
   assignments may differ but the paper's metrics must stay within tight
   bands of the oracle.
-* the fused Pallas epoch kernel matches the unfused jnp pipeline
-  (``_match_counts`` + segment-count) slot for slot.
 """
 
 import numpy as np
 import pytest
-
-from hypothesis_compat import given, settings, st
 
 from repro.analysis.contracts import DRIFT_SCHEMES, EXACT_SCHEMES
 from repro.core import simulate_edge
@@ -173,102 +169,3 @@ def test_chk_batch_matches_scalar_elementwise():
             d_s, m_s = chk_num_workers(float(f[i]), f_top, theta, w,
                                        m_k=int(m_prev[i]))
             assert (int(d_b[i]), int(m_b[i])) == (d_s, m_s), (i, f[i])
-
-
-# ---------------------------------------------------------------------------
-# fused Pallas epoch kernel vs the unfused jnp pipeline
-# ---------------------------------------------------------------------------
-
-
-def _fused_vs_unfused(table, tcounts, batch, alpha):
-    import jax.numpy as jnp
-
-    from repro.core.fish import _match_counts
-    from repro.kernels import ops
-
-    new_c, matched, cand, first = ops.fish_epoch_count(
-        jnp.asarray(table), jnp.asarray(tcounts), jnp.asarray(batch),
-        alpha=alpha,
-    )
-    delta, matched_ref = _match_counts(jnp.asarray(table), jnp.asarray(batch))
-    np.testing.assert_allclose(np.asarray(new_c),
-                               np.asarray(tcounts) * alpha + np.asarray(delta),
-                               rtol=1e-5, atol=1e-5)
-    np.testing.assert_array_equal(np.asarray(matched), np.asarray(matched_ref))
-    # candidate histogram: per-position epoch frequency of its own key,
-    # deduped by the first-occurrence flag == np.unique segment counts
-    cand = np.asarray(cand)
-    first = np.asarray(first)
-    uniq, counts = np.unique(batch, return_counts=True)
-    seen = {}
-    for i, k in enumerate(batch.tolist()):
-        assert cand[i] == counts[np.searchsorted(uniq, k)]
-        assert first[i] == (k not in seen)
-        seen[k] = True
-
-
-def test_fused_epoch_kernel_matches_unfused():
-    rng = np.random.default_rng(3)
-    table = np.full(128, -1, np.int32)
-    table[:90] = rng.choice(4_000, 90, replace=False)
-    tcounts = np.zeros(128, np.float32)
-    tcounts[:90] = rng.gamma(2.0, 3.0, 90).astype(np.float32)
-    batch = rng.integers(0, 5_000, 1_500).astype(np.int32)
-    _fused_vs_unfused(table, tcounts, batch, alpha=0.2)
-
-
-@given(st.integers(1, 300), st.integers(1, 80), st.integers(0, 2**31 - 1))
-@settings(max_examples=15, deadline=None)
-def test_fused_epoch_kernel_property(n_keys, n_table, seed):
-    rng = np.random.default_rng(seed)
-    k_slots = 128
-    table = np.full(k_slots, -1, np.int32)
-    table[:n_table] = rng.choice(1_000, n_table, replace=False)
-    tcounts = np.zeros(k_slots, np.float32)
-    tcounts[:n_table] = rng.gamma(2.0, 2.0, n_table).astype(np.float32)
-    batch = rng.integers(0, 1_200, n_keys).astype(np.int32)
-    _fused_vs_unfused(table, tcounts, batch, alpha=0.5)
-
-
-def test_epoch_update_partial_epoch_smaller_than_max_new():
-    """A final partial epoch with fewer tuples than max_new must not crash
-    (top_k k-clamp) on either the jnp or the fused path."""
-    import jax.numpy as jnp
-
-    from repro.core.fish import epoch_update, init_fish_state
-    from repro.kernels import ops
-
-    state = init_fish_state(128)
-    state = epoch_update(state, jnp.arange(10, dtype=jnp.int32), alpha=0.2,
-                         max_new=64)
-    state = epoch_update(state, jnp.arange(5, 15, dtype=jnp.int32), alpha=0.2,
-                         max_new=64, fused_fn=ops.fish_epoch_count)
-    assert int((np.asarray(state["keys"]) >= 0).sum()) == 15
-
-
-def test_epoch_update_fused_tracks_sequential_oracle():
-    """End-to-end: fused-kernel epoch_update follows the sequential Alg. 1
-    tracker through the ZF hot-set flip (same bound as the jnp path)."""
-    import jax.numpy as jnp
-
-    from repro.core import EpochFrequencyTracker, FishParams
-    from repro.core.fish import epoch_update, init_fish_state
-    from repro.kernels import ops
-
-    p = FishParams(alpha=0.2, epoch=1000, k_max=256)
-    zkeys = zipf_time_evolving(16_000, num_keys=2_000, z=1.4, seed=7
-                               ).astype(np.int32)
-    seq = EpochFrequencyTracker(p)
-    seq.update_many(zkeys.tolist())
-
-    state = init_fish_state(p.k_max)
-    for i in range(0, len(zkeys), p.epoch):
-        state = epoch_update(state, jnp.asarray(zkeys[i:i + p.epoch]),
-                             alpha=p.alpha, max_new=64,
-                             fused_fn=ops.fish_epoch_count)
-    top_seq = set(sorted(seq.counts, key=seq.counts.get, reverse=True)[:20])
-    ks = np.asarray(state["keys"])
-    cs = np.asarray(state["counts"])
-    top_dev = set(ks[np.argsort(-cs)][:20].tolist())
-    jac = len(top_seq & top_dev) / len(top_seq | top_dev)
-    assert jac >= 0.6, f"fused/oracle hot-set Jaccard too low: {jac}"

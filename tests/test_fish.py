@@ -1,13 +1,8 @@
 """Unit + property tests for the FISH core algorithms (paper Algs. 1-3)."""
 
-import math
-
-import numpy as np
-import pytest
 from hypothesis_compat import given, settings, st
 
-from repro.core import (EpochFrequencyTracker, FishParams, chk_num_workers,
-                        classify_hot_keys, epoch_update, init_fish_state)
+from repro.core import EpochFrequencyTracker, FishParams, chk_num_workers
 from repro.data.synthetic import zipf_time_evolving
 
 
@@ -128,50 +123,3 @@ def test_chk_bounds(f_k, f_top, w):
     f_top = max(f_k, f_top)
     d, _ = chk_num_workers(f_k, f_top, theta=0.25 / w, num_workers=w)
     assert 2 <= d <= w
-
-
-# ---------------------------------------------------------------------------
-# Device-side epoch_update vs. the sequential oracle
-# ---------------------------------------------------------------------------
-
-
-def test_epoch_update_matches_oracle_hot_sets():
-    import jax.numpy as jnp
-
-    p = FishParams(alpha=0.2, epoch=1000, k_max=256)
-    keys = zipf_time_evolving(16_000, num_keys=2_000, z=1.4, seed=7
-                              ).astype(np.int32)
-    seq = EpochFrequencyTracker(p)
-    seq.update_many(keys.tolist())
-
-    st_dev = init_fish_state(p.k_max)
-    for i in range(0, len(keys), p.epoch):
-        st_dev = epoch_update(st_dev, jnp.asarray(keys[i:i + p.epoch]),
-                              alpha=p.alpha, max_new=64)
-    top_seq = set(sorted(seq.counts, key=seq.counts.get, reverse=True)[:20])
-    ks = np.asarray(st_dev["keys"])
-    cs = np.asarray(st_dev["counts"])
-    top_dev = set(ks[np.argsort(-cs)][:20].tolist())
-    jac = len(top_seq & top_dev) / len(top_seq | top_dev)
-    assert jac >= 0.6, f"device/oracle hot-set Jaccard too low: {jac}"
-
-
-def test_classify_hot_keys_vectorised_matches_scalar():
-    import jax.numpy as jnp
-
-    state = init_fish_state(8)
-    state["keys"] = jnp.arange(8, dtype=jnp.int32)
-    counts = jnp.asarray([100.0, 50.0, 25.0, 12.0, 6.0, 3.0, 1.0, 1.0])
-    state["counts"] = counts
-    w = 64
-    theta = 0.25 / w
-    d, is_hot, _ = classify_hot_keys(state, num_workers=w, theta=theta)
-    total = float(counts.sum())
-    f_top = float(counts.max()) / total
-    for i in range(8):
-        f_k = float(counts[i]) / total
-        d_ref, _ = chk_num_workers(f_k, f_top, theta, w)
-        if f_k > theta:
-            assert int(d[i]) == d_ref, (i, int(d[i]), d_ref)
-        else:
-            assert int(d[i]) == 2

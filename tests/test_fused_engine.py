@@ -31,7 +31,6 @@ from repro.core.stream import simulate_edge
 from repro.data.synthetic import zipf_time_evolving
 from repro.kernels import feed_fused
 from repro.state import WindowOp, direct_aggregate
-from repro.state.store import ArrayStateStore, DeviceStateStore, DictStateStore
 from repro.topology import (Edge, ScopedEvent, ServingTopologyEngine,
                             SimulatorEngine, Source, Stage, Topology,
                             WindowOp as TopoWindowOp, config_for)
@@ -189,7 +188,7 @@ def _merge_topo(scheme, backend="array", workers=6):
                Edge("count", "merge", config_for("fg"))))
 
 
-@pytest.mark.parametrize("backend", ("dict", "array", "device"))
+@pytest.mark.parametrize("backend", ("dict", "array"))
 def test_fused_merge_stage_matches_batched(backend, keys, values):
     src = Source(keys, arrival_rate=2e4, values=values)
     rb = _run("batched", _merge_topo("fg", backend), src, feeds=4)
@@ -396,56 +395,6 @@ def test_fused_rejects_state_sink_in_host_modes(keys):
     with pytest.raises(ValueError, match="state_sink"):
         simulate_edge(g, keys[:100], arrival_rate=1e4, mode="batched",
                       state_sink=mgr)
-
-
-# ---------------------------------------------------------------------------
-# device-resident state store backend
-# ---------------------------------------------------------------------------
-
-
-def _fill(store, rng, rounds=5):
-    for _ in range(rounds):
-        ks = rng.integers(0, 500, 300)
-        vs = rng.integers(1, 100, 300)
-        store.update_batch(ks, vs)
-
-
-def test_device_store_matches_dict_store():
-    rng1, rng2 = (np.random.default_rng(9) for _ in range(2))
-    dev, ref = DeviceStateStore(), DictStateStore()
-    _fill(dev, rng1), _fill(ref, rng2)
-    dk, dv, dc = dev.items()
-    rk, rv, rc = ref.items()
-    order = np.argsort(rk, kind="stable")
-    np.testing.assert_array_equal(dk, rk[order])
-    np.testing.assert_array_equal(dv, rv[order])
-    np.testing.assert_array_equal(dc, rc[order])
-    assert dev.num_entries == ref.num_entries
-    assert dev.size_bytes() == ref.size_bytes()
-
-
-def test_device_store_take_and_merge_roundtrip():
-    dev, ref = DeviceStateStore(), ArrayStateStore()
-    ks = np.arange(40, dtype=np.int64)
-    vs = (ks * 7 + 1)
-    dev.update_batch(ks, vs), ref.update_batch(ks, vs)
-    tk = np.array([3, 17, 39], dtype=np.int64)
-    vd, cd = dev.take(tk)
-    vr, cr = ref.take(tk)
-    np.testing.assert_array_equal(vd, vr)
-    np.testing.assert_array_equal(cd, cr)
-    assert dev.num_entries == ref.num_entries
-    # migrated entries land back exactly (the §9 churn protocol)
-    dev.merge_entries(tk, vd, cd), ref.merge_entries(tk, vr, cr)
-    np.testing.assert_array_equal(dev.items()[1], ref.items()[1])
-    with pytest.raises(KeyError):
-        dev.take(np.array([999]))
-
-
-def test_device_store_guards_int32_range():
-    dev = DeviceStateStore()
-    with pytest.raises(ValueError, match="int32"):
-        dev.update_batch(np.array([2**40]), np.array([1]))
 
 
 # ---------------------------------------------------------------------------

@@ -1,81 +1,20 @@
 """Regression tests for >2^31 aggregates (ISSUE 10 satellite 1).
 
-The device store accumulates in int32 on device (no x64), so lifetime
-totals past 2^31 must flow through the generational spill into the host
-int64 base; the fused feed uploads per-worker counts as int32, so counts
-past 2^31 must survive the rebase/readback round trip.  Both paths feed
-billing (``size_bytes``/``MigrationBiller``), which is where silent
-wraparound would turn into silently-wrong charges.
+The state stores hold int64 totals, and the fused feed uploads
+per-worker counts as int32, so counts past 2^31 must survive the
+rebase/readback round trip.  Both feed billing
+(``size_bytes``/``MigrationBiller``), which is where silent wraparound
+would turn into silently-wrong charges.
 """
 import numpy as np
 import pytest
 
 from repro.core.stream import simulate_edge
 from repro.state.migration import MigrationBiller, MigrationStats
-from repro.state.store import (ENTRY_BYTES, ArrayStateStore,
-                               DeviceStateStore, DictStateStore)
+from repro.state.store import ENTRY_BYTES, ArrayStateStore
 from repro.topology.configs import config_for
 
 INT32_MAX = 2 ** 31 - 1
-
-
-def test_device_store_lifetime_totals_past_int32():
-    st = DeviceStateStore()
-    chunk = 2 ** 30
-    for _ in range(3):  # 3 * 2^30 > INT32_MAX: forces at least one spill
-        st.merge_entries(np.array([3, 7], dtype=np.int64),
-                         np.array([chunk, chunk], dtype=np.int64),
-                         np.array([chunk, chunk], dtype=np.int64))
-    ks, vs, cs = st.items()
-    assert ks.tolist() == [3, 7]
-    assert vs.tolist() == [3 * chunk, 3 * chunk]
-    assert cs.tolist() == [3 * chunk, 3 * chunk]
-    assert vs.dtype == np.int64 and min(vs) > INT32_MAX
-    # the young generation must have spilled into the int64 base
-    assert st._base_c.max() > 0
-    vals, cnts = st.take(np.array([3], dtype=np.int64))
-    assert vals.tolist() == [3 * chunk] and cnts.tolist() == [3 * chunk]
-    assert st.num_entries == 1  # key 3 drained, key 7 intact
-    _, vs2, _ = st.items()
-    assert vs2.tolist() == [3 * chunk]
-
-
-def test_device_store_spill_survives_key_rebuild():
-    """Inserting unseen keys after a spill must realign the int64 base."""
-    st = DeviceStateStore()
-    big = 2 ** 30
-    st.merge_entries(np.array([10], dtype=np.int64),
-                     np.array([big], dtype=np.int64),
-                     np.array([big], dtype=np.int64))
-    st.merge_entries(np.array([10], dtype=np.int64),
-                     np.array([big], dtype=np.int64),
-                     np.array([big], dtype=np.int64))
-    # key 5 sorts *before* key 10: the rebuild shifts device slots and
-    # must shift the spilled base with them
-    st.merge_entries(np.array([5, 10], dtype=np.int64),
-                     np.array([1, big], dtype=np.int64),
-                     np.array([1, big], dtype=np.int64))
-    ks, vs, cs = st.items()
-    assert ks.tolist() == [5, 10]
-    assert vs.tolist() == [1, 3 * big]
-    assert cs.tolist() == [1, 3 * big]
-
-
-def test_device_store_matches_dict_reference_under_repeated_merges():
-    rng = np.random.default_rng(11)
-    dev, ref = DeviceStateStore(), DictStateStore()
-    for _ in range(12):
-        keys = np.unique(rng.integers(0, 40, size=16))
-        vals = rng.integers(1, 2 ** 30, size=keys.shape[0])
-        cnts = rng.integers(1, 2 ** 30, size=keys.shape[0])
-        dev.merge_entries(keys, vals, cnts)
-        ref.merge_entries(keys, vals, cnts)
-    dk, dv, dc = dev.items()
-    rk, rv, rc = ref.items()
-    order = np.argsort(rk)
-    np.testing.assert_array_equal(dk, rk[order])
-    np.testing.assert_array_equal(dv, rv[order])
-    np.testing.assert_array_equal(dc, rc[order])
 
 
 def test_array_store_totals_past_int32():

@@ -1,5 +1,5 @@
 """Topology API (ISSUE 3): typed configs, multi-stage engines vs the
-per-tuple reference oracle, deprecation shims, scoped events.
+per-tuple reference oracle, scoped events.
 
 Equivalence contract (extends DESIGN.md §6 to multi-hop):
 
@@ -10,17 +10,18 @@ Equivalence contract (extends DESIGN.md §6 to multi-hop):
   assignments but every per-edge paper metric stays within tight bands.
 """
 
-import warnings
-
 import numpy as np
 import pytest
 
-from repro.core import MembershipEvent, make_grouper, simulate_stream
+from repro.core import (DChoices, FieldGrouping, FishGrouper,
+                        MembershipEvent, PartialKeyGrouping, ShuffleGrouping,
+                        WChoices)
 from repro.data.synthetic import zipf_time_evolving
 from repro.topology import (SCHEME_CONFIGS, DChoicesConfig, Edge, FishConfig,
                             ScopedEvent, ServingTopologyEngine, ShuffleConfig,
                             SimulatorEngine, Source, Stage, Topology,
-                            config_for, hashed_fanout, project_mod)
+                            build_grouper, config_for, hashed_fanout,
+                            project_mod)
 
 from repro.analysis.contracts import (DRIFT_SCHEMES, EXACT_SCHEMES,
                                       SCHEMES)
@@ -48,16 +49,16 @@ def _word_count(spec, split_w=5, count_w=7, fanout=3, vocab=300):
 
 
 @pytest.mark.parametrize("scheme", SCHEMES)
-def test_config_build_matches_legacy_make_grouper(scheme):
+def test_config_build_names_its_scheme(scheme):
     cfg = config_for(scheme)
-    g_new = cfg.build(8)
-    with warnings.catch_warnings():
-        warnings.simplefilter("ignore", DeprecationWarning)
-        g_old = make_grouper(scheme, 8)
-    assert type(g_new) is type(g_old)
-    assert cfg.scheme == scheme == g_new.name
+    g = cfg.build(8)
+    assert type(g) is {
+        "sg": ShuffleGrouping, "fg": FieldGrouping, "pkg": PartialKeyGrouping,
+        "dc": DChoices, "wc": WChoices, "fish": FishGrouper}[scheme]
+    assert cfg.scheme == scheme == g.name
+    g2 = build_grouper(scheme, 8)
     for k in range(200):
-        assert g_new.probe_route(k) == g_old.probe_route(k), k
+        assert g.probe_route(k) == g2.probe_route(k), k
 
 
 def test_config_validation_rejects_bad_fields():
@@ -85,15 +86,6 @@ def test_configs_are_reusable_values():
     assert g2.memory_overhead() == 0  # builds never share state
     assert cfg == FishConfig(epoch=100)  # frozen value semantics
     assert hash(cfg) == hash(FishConfig(epoch=100))
-
-
-def test_deprecation_shims_warn():
-    with pytest.warns(DeprecationWarning, match="make_grouper"):
-        g = make_grouper("pkg", 4)
-    with pytest.warns(DeprecationWarning, match="simulate_stream"):
-        m = simulate_stream(g, np.arange(100, dtype=np.int64) % 7,
-                            arrival_rate=1e3)
-    assert m.execution_time > 0
 
 
 # ---------------------------------------------------------------------------
