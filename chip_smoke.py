@@ -141,7 +141,10 @@ def topology(scheme: str, workers: int, merge_workers: int, window: int):
 
 
 def _device_tables(runner):
-    names = ("repl", "trk", "m_k", "pane_tab", "pane_last")
+    # the per-key tables, the open pane and the ring tables; the replica
+    # sets are kept on the host
+    names = ("trk", "m_k", "pane_tab", "pane_last", "_pts_dev",
+             "_cands_dev", "_rank_dev")
     return {n: getattr(runner, n) for n in names
             if getattr(runner, n) is not None}
 
@@ -170,7 +173,10 @@ def run_fused(topo, keys, values, feed: int, platform: str):
                 runner = sess._st[COUNT_EDGE].state.device
                 require(hasattr(runner, "pane_tab"),
                         f"{topo.name}: the count edge has no fused runner")
-                for name, arr in _device_tables(runner).items():
+                tables = _device_tables(runner)
+                require(tables, f"{topo.name}: the count edge holds no "
+                        "device table after its first feed")
+                for name, arr in tables.items():
                     where = {d.platform for d in arr.devices()}
                     require(where == {platform},
                             f"{topo.name}: device table {name} lives on "

@@ -28,11 +28,12 @@ c. **keyed-state update** — per-(worker, pane slot) aggregate tables
 
 A steady-state ``session.feed(batch)`` is therefore **one** device
 dispatch (counted in :attr:`FusedEdgeRunner.dispatches`, surfaced as
-``EdgeResult.dispatches``): per-key state (tracker, CHK memory, replica
-matrix, pane tables) stays device-resident across feeds; only the small
-per-worker vectors (busy, counts, estimator) and the per-tuple finish
-times (and, with a pane, workers) cross the boundary as part of the
-launch round-trip.
+``EdgeResult.dispatches``): per-key state (tracker, CHK memory, pane
+tables) stays device-resident across feeds; only the small per-worker
+vectors (busy, counts, estimator) and the per-tuple finish times and
+workers cross the boundary as part of the launch round-trip.  The
+replica sets are kept on the host, from the (worker, key) pairs those
+workers name.
 
 Shape discipline: segment lengths pad to power-of-two buckets (min
 :data:`MIN_BUCKET`) so varying RecordBatch lengths reuse one trace;
@@ -85,9 +86,10 @@ _TRACE_COUNTER = GLOBAL_METRICS.counter("fused.trace_count")
 _NULL_TELEMETRY = Telemetry(enabled=False)
 MIN_BUCKET = 64  # smallest pow2 padding bucket for segment lengths
 #: Dense per-key tables; larger key ids fall back.  At 2^24 + 1 rows the
-#: per-key tables of a 128-worker edge (tracker, candidate memory, the
-#: 129-lane replica matrix, 2.28 GB as a v5e lays it out) and of its
-#: 32-worker merge edge fit one 16 GB chip with room for a growth copy.
+#: per-key device tables of a 128-worker edge (tracker, candidate memory,
+#: 67 MB each) and of its 32-worker merge edge fit one 16 GB chip with
+#: room for a growth copy; the host's replica bitmap of the 128-worker
+#: edge is 2.16 GB.
 KEY_CAP_LIMIT = 1 << 24
 
 _SEG_CACHE: dict = {}  # static signature -> jitted segment function
@@ -413,10 +415,12 @@ def _get_seg_fn(sig):
     rows) is the recompile boundary.
 
     Each phase runs under a ``jax.named_scope`` (``route/ring``,
-    ``route/tracker``, ``route/choose``, ``fifo``, ``replicas``, and with
-    a pane ``pane/scatter`` and ``pane/last``), which names its operations
-    in a device trace's ``tf_op`` metadata; the compiled program is the
-    same.  The module is ``jit_seg_<scheme>``."""
+    ``route/tracker``, ``route/choose``, ``fifo``, and with a pane
+    ``pane/scatter`` and ``pane/last``), which names its operations in a
+    device trace's ``tf_op`` metadata; the compiled program is the same.
+    Every launch returns each tuple's worker (``workers``), from which the
+    host keeps the replica sets and the pane's touched entries.  The
+    module is ``jit_seg_<scheme>``."""
     fn = _SEG_CACHE.get(sig)
     if fn is not None:
         return fn
@@ -425,9 +429,9 @@ def _get_seg_fn(sig):
     fifo = _fifo_scan if fifo_impl == "scan" else _fifo_assoc
 
     def seg(dev, a):
-        # `dev` holds the per-key device tables (replica matrix, tracker,
-        # pane planes) — donated, so XLA updates them in place instead of
-        # copying the ~MB accumulators every launch
+        # `dev` holds the per-key device tables (tracker, candidate
+        # memory, pane planes) — donated, so XLA updates them in place
+        # instead of copying the ~MB accumulators every launch
         _TRACE_COUNTER.add(1)  # runs at trace time only
         a = dict(a)
         a.update(dev)
@@ -479,8 +483,10 @@ def _get_seg_fn(sig):
         out["fin"] = fin
         out["busy"] = busy
         out["counts"] = counts
-        with jax.named_scope("replicas"):
-            out["repl"] = a["repl"].at[a["keys"], workers].set(True)
+        # each tuple's worker: with the host's keys it names the launch's
+        # (worker, key) replica pairs and, with a pane, every entry the
+        # pane touched, so the flush gathers those alone
+        out["workers"] = workers
         if has_pane:
             with jax.named_scope("pane/scatter"):
                 # one stacked scatter updates value and count planes
@@ -504,10 +510,6 @@ def _get_seg_fn(sig):
                 pane = base.at[flat].add(
                     vc, mode="promise_in_bounds").reshape(w1, s1, 2)
             out["pane_tab"] = pane
-            # each tuple's worker: with the host's keys it names every
-            # (worker, key) entry the pane touched, so the flush gathers
-            # those alone instead of reading the dense planes
-            out["workers"] = workers
             with jax.named_scope("pane/last"):
                 gidx = a["seg_base"] + jnp.arange(n_pad, dtype=jnp.int32)
                 gidx = jnp.where(a["valid"], gidx, -1)
@@ -565,12 +567,20 @@ class FusedEdgeRunner:
     """Device-resident execution state of one fused edge.
 
     Lives on ``EdgeState.device`` across feeds.  Per-key state —
-    frequency tracker, CHK memory, replica matrix, open pane tables —
-    stays on device between launches; per-worker vectors (busy, counts,
-    estimator) round-trip with each launch as arguments/outputs, keeping
-    the host copies authoritative so event handling and metrics never
-    need a separate sync.  ``host_sync`` folds the replica matrix back
-    into the grouper — called before metrics/close and membership events.
+    frequency tracker, CHK memory, open pane tables — stays on device
+    between launches; per-worker vectors (busy, counts, estimator)
+    round-trip with each launch as arguments/outputs, keeping the host
+    copies authoritative so event handling and metrics never need a
+    separate sync.
+
+    The replica sets never reach the device.  Each launch hands back its
+    tuples' workers, and the host folds the (worker, key) pairs into a
+    bitmap of the pairs seen so far (``(w1, kcap + 1)`` bool,
+    worker-major), remembering the pairs seen for the first time: at the
+    pane flush, from the pairs it dedupes anyway; on an edge without a
+    pane, at each launch's readback.  ``host_sync`` adds those new pairs
+    to ``grouper.replicas`` — called before metrics/close and membership
+    events.
 
     The open pane is keyed by slot, not by key id: the host gives each key
     the next free slot the first time it appears in the pane (a dense
@@ -625,14 +635,17 @@ class FusedEdgeRunner:
         self._rank_dev = None     # FISH: inverse ring table (device)
         self._hash_arr = None     # dense key -> hash32 cache (np uint32)
         self._hash_ok = None
-        self._repl_dirty = False
+        # (w1, kcap + 1) bool: the (worker, key) replica pairs seen so far
+        self._repl_seen = None
+        # (workers, keys) of the pairs first seen since the last host_sync
+        self._repl_new: list = []
+        self._c_repl_new = self.tel.metrics.counter(
+            "fused.replica_pairs_new", scheme=self.scheme)
         # device-resident per-key state
         self.trk = None
         self.m_k = None
-        self.repl = None
         self.pane_tab = None      # (w1, slots + 1, 2): value / count planes
         self.pane_last = None
-        self._repl_synced = None  # host mirror of already-synced pairs
 
     @property
     def dispatches(self) -> int:
@@ -699,17 +712,41 @@ class FusedEdgeRunner:
 
     def _grow_repl(self, old_k: int, old_w: int, new_kcap: int,
                    w1: int) -> None:
-        """Grow the (key, worker) replica matrix and its host mirror."""
-        self.repl = _grow_dev2(self.repl, old_k, old_w, new_kcap + 1, w1,
-                               jnp.bool_)
-        self._repl_synced = _grow_host2(self._repl_synced, old_k, old_w,
-                                        new_kcap + 1, w1)
+        """Grow the host bitmap of seen replica pairs.  It is written in
+        full as it is allocated, so no fold pays its first-touch page
+        faults inside a feed."""
+        out = np.full((w1, new_kcap + 1), False)
+        if self._repl_seen is not None:
+            # the old phantom lane and key column never hold a pair
+            out[:old_w, :old_k] = self._repl_seen[:old_w, :old_k]
+        self._repl_seen = out
 
     def _key_table_bytes(self) -> int:
         """Bytes of the per-key tables, on the device and on the host."""
         return sum(int(t.nbytes) for t in (
-            self.trk, self.m_k, self.repl, self._repl_synced,
-            self._hash_arr, self._hash_ok, self._slot_of) if t is not None)
+            self.trk, self.m_k, self._repl_seen, self._hash_arr,
+            self._hash_ok, self._slot_of) if t is not None)
+
+    def _fold_replicas(self, ws: np.ndarray, ks: np.ndarray) -> None:
+        """Mark the (worker, key) pairs ``(ws[i], ks[i])`` seen, and keep
+        those seen for the first time for the next ``host_sync``, under
+        the span ``fused.replica_fold`` (args ``pairs``, ``new``).  Pairs
+        may repeat; each is new once, so the kept list is bounded by the
+        distinct pairs, never by the stream."""
+        with self.tel.tracer.span("fused.replica_fold", cat="fused",
+                                  pairs=int(ws.shape[0])) as fold_span:
+            kcap1 = self._kcap + 1
+            seen = self._repl_seen.reshape(-1)  # a view: writes land
+            flat = ws.astype(np.int64) * kcap1 + ks
+            fresh = flat[~seen[flat]]
+            if fresh.shape[0]:
+                fresh = np.unique(fresh)  # repeats within these pairs
+                seen[fresh] = True
+                w, k = np.divmod(fresh, kcap1)
+                self._repl_new.append((w.astype(np.int32),
+                                       k.astype(np.int32)))
+                self._c_repl_new.add(int(fresh.shape[0]))
+            fold_span.set(new=int(fresh.shape[0]))
 
     def refresh_membership(self, grouper, state) -> None:
         """Rebuild the device ring table + live-set arrays after a
@@ -836,7 +873,7 @@ class FusedEdgeRunner:
         # per array (the dominant host overhead at 16k-tuple feeds).
         # Per-key tables ride in `dev`, the donated arg: each is replaced
         # by its updated output, never read again through the old handle.
-        dev = {"repl": self.repl}
+        dev = {}
         a = {"keys": keys_i, "m": np.int32(m), "t": t, "busy": busy,
              "caps": caps, "counts": counts}
         r_n = 0
@@ -897,7 +934,6 @@ class FusedEdgeRunner:
             self._c_choice_tuples.add(m)
 
         # device-resident state stays device-side
-        self.repl = out["repl"]
         if "trk" in out:
             self.trk = out["trk"]
         if "m_k" in out:
@@ -906,7 +942,6 @@ class FusedEdgeRunner:
             self.pane_tab = out["pane_tab"]
             self.pane_last = out["pane_last"]
             self.pane_fed += m
-        self._repl_dirty = True
 
         # small per-worker vectors ride back with the launch's output fetch
         with tracer.span("fused.segment.readback", cat="fused"):
@@ -924,9 +959,12 @@ class FusedEdgeRunner:
                 est.assigned[:] = np.asarray(out["eas"],
                                              dtype=np.float64)[:nw]
             fin = self._base + np.asarray(out["fin"], dtype=np.float64)[:m]
-            if self.has_pane:
-                self._pane_pairs.append((np.asarray(out["workers"])[:m],
-                                         self._feed_keys[lo:hi]))
+            workers = np.asarray(out["workers"])[:m]
+        if self.has_pane:
+            # the flush folds the pane's pairs once it has deduped them
+            self._pane_pairs.append((workers, self._feed_keys[lo:hi]))
+        else:
+            self._fold_replicas(workers, self._feed_keys[lo:hi])
         if (scheme == "fish" and self.tel.enabled
                 and self._fish_epochs_crossed):
             # the tracker read is a cost of tracing: its own span shows it
@@ -1052,6 +1090,7 @@ class FusedEdgeRunner:
             [[0], np.flatnonzero(ws[1:] != ws[:-1]) + 1, [live]]).tolist()
         runs = list(zip(starts[:-1], starts[1:]))  # one run per worker
         scan_span.set(touched=int(ws_all.shape[0]), live=live).done()
+        self._fold_replicas(ws, ks)
         with tracer.span("fused.pane_flush.copy", cat="fused",
                          array="entries") as copy_span:
             got, last = jax.device_get(
@@ -1077,22 +1116,24 @@ class FusedEdgeRunner:
         flush_span.done()
 
     def host_sync(self, grouper) -> None:
-        """Fold device-resident per-key state back into the grouper: new
-        (key, worker) replica pairs since the last sync.  Called before
-        metrics/close and before membership events."""
-        if not self._repl_dirty:
+        """Add the (key, worker) replica pairs first seen since the last
+        sync to ``grouper.replicas``, in key-major order, the open pane's
+        included: its pairs are folded here and kept for its flush.
+        Called before metrics/close and before membership events."""
+        if self._pane_pairs:
+            self._fold_replicas(
+                np.concatenate([w for w, _ in self._pane_pairs]),
+                np.concatenate([k for _, k in self._pane_pairs]))
+        if not self._repl_new:
             return
         self._c_host_syncs.add(1)
         with self.tel.tracer.span("fused.host_sync", cat="fused"):
-            dev = np.asarray(self.repl)
-            new = dev[:-1, :-1] & ~self._repl_synced[:-1, :-1]
-            for k, w in zip(*np.nonzero(new)):
-                grouper.replicas.setdefault(int(k), set()).add(int(w))
-            # on the CPU backend asarray is a view of the device buffer,
-            # which the next launch takes by donation — copy it (on a TPU
-            # asarray already copied device to host)
-            self._repl_synced = dev.copy()
-            self._repl_dirty = False
+            ws = np.concatenate([w for w, _ in self._repl_new])
+            ks = np.concatenate([k for _, k in self._repl_new])
+            order = np.lexsort((ws, ks))
+            for k, w in zip(ks[order].tolist(), ws[order].tolist()):
+                grouper.replicas.setdefault(k, set()).add(w)
+            self._repl_new = []
 
 
 # -- growth helpers (rare: each growth is a recompile boundary) -------------
@@ -1110,28 +1151,12 @@ def _grow_dev1(arr, old, new1, dtype):
     return out if arr is None else out.at[:old].set(arr[:old])
 
 
-def _grow_dev2(arr, old_k, old_w, kcap1, w1, dtype):
-    out = jnp.zeros((kcap1, w1), dtype)
-    if arr is None:
-        return out
-    # the old phantom column (old_w - 1) may only hold phantom-row entries,
-    # which the [:old_k] row slice already drops — safe to copy columns
-    return out.at[:old_k, :old_w].set(arr[:old_k, :old_w])
-
-
 def _grow_pane(arr, old_w, old_s, w1, slots):
     # pane tables are worker-major: (w1, slots + 1, 2).  The old phantom
     # slot row (index old_s) only holds the padding lanes' entries in the
     # old phantom lane: the [:old_w, :old_s] copy drops both
     out = jnp.zeros((w1, slots + 1, 2), jnp.int32)
     return out.at[:old_w, :old_s, :].set(arr[:old_w, :old_s, :])
-
-
-def _grow_host2(arr, old_k, old_w, kcap1, w1):
-    out = np.zeros((kcap1, w1), bool)
-    if arr is not None:
-        out[:old_k, :old_w] = arr[:old_k, :old_w]
-    return out
 
 
 def _grow_last(arr, old_w, w1):

@@ -27,10 +27,12 @@ import numpy as np
 import pytest
 
 from repro.core import CapacityEvent, MembershipEvent
+from repro.core import stream
 from repro.core.stream import simulate_edge
 from repro.data.synthetic import zipf_time_evolving
 from repro.kernels import feed_fused
-from repro.state import WindowOp, direct_aggregate
+from repro.obs import Telemetry
+from repro.state import KeyedStateManager, WindowOp, direct_aggregate
 from repro.topology import (Edge, ScopedEvent, ServingTopologyEngine,
                             SimulatorEngine, Source, Stage, Topology,
                             WindowOp as TopoWindowOp, config_for)
@@ -66,7 +68,8 @@ def _run(mode, topo, src, events=(), feeds=1):
     sess = SimulatorEngine(mode=mode).open(
         topo, arrival_rate=src.arrival_rate)
     if events:
-        sess.advance(events)
+        if events:
+            sess.advance(events)
     n = int(src.keys.shape[0])
     for batch in src.iter_batches(batch_size=-(-n // feeds)):
         sess.feed(batch)
@@ -172,6 +175,124 @@ def test_fused_multi_feed_with_events(scheme, keys, values):
         assert ef.latency_p99 == pytest.approx(eb.latency_p99, rel=F32_REL)
         assert rf.state["agg"]["migration_bytes"] == \
             rb.state["agg"]["migration_bytes"]
+
+
+# ---------------------------------------------------------------------------
+# replica sets: kept on the host from the workers every launch returns
+# ---------------------------------------------------------------------------
+
+
+def _two_stage(scheme):
+    """A windowed ``count`` stage and a ``merge`` stage with no operator,
+    both edges grouped by ``scheme``: the merge edge has no pane."""
+    op = TopoWindowOp(agg="sum", value="payload", size=1_024)
+    return Topology(name="r", stages=(
+        Stage("count", 8, operator=op), Stage("merge", 4)),
+        edges=(Edge("source", "count", config_for(scheme)),
+               Edge("count", "merge", config_for(scheme))))
+
+
+def _replicas(grouper):
+    return {k: set(ws) for k, ws in grouper.replicas.items()}
+
+
+def _session_replicas(mode, topo, src, events, monkeypatch):
+    """Each edge's ``grouper.replicas`` at close, and the reshaped
+    grouper's at every membership event, as the event handler sees it."""
+    at_events = []
+    real = stream._apply_events
+
+    def spy(i, mem_ev, ev_idx, cap_ev, cap_idx, grouper, *rest):
+        if ev_idx < len(mem_ev) and mem_ev[ev_idx].at == i:
+            at_events.append(_replicas(grouper))
+        return real(i, mem_ev, ev_idx, cap_ev, cap_idx, grouper, *rest)
+
+    with monkeypatch.context() as mp:
+        mp.setattr(stream, "_apply_events", spy)
+        sess = SimulatorEngine(mode=mode).open(
+            topo, arrival_rate=src.arrival_rate)
+        if events:
+            sess.advance(events)
+        for batch in src.iter_batches(batch_size=1_500):
+            sess.feed(batch)
+        rep = sess.close()
+    at_close = {name: _replicas(st.grouper)
+                for name, st in sess._st.items()}
+    return rep, at_close, at_events
+
+
+def _report_replicas(mode, scheme, keys):
+    """``grouper.replicas`` after each of two ``simulate_edge`` calls on
+    one carried state, each ending 476 or 952 tuples into an open pane:
+    the fused edge syncs for its metrics with the pane unflushed."""
+    g = config_for(scheme).build(8)
+    sink = (KeyedStateManager(WindowOp(agg="count", size=1_024))
+            if mode == "fused" else None)
+    caps = np.full(8, 4e-4)
+    state, out = None, []
+    for lo, hi in ((0, 1_500), (1_500, 3_000)):
+        ts = np.arange(lo, hi, dtype=np.float64) / 2e4
+        res = simulate_edge(g, keys[lo:hi], times=ts, arrival_rate=2e4,
+                            mode=mode, capacities=caps, state=state,
+                            state_sink=sink)
+        state = res.state
+        out.append((_replicas(g), res.metrics.memory_overhead))
+    return out
+
+
+@pytest.mark.parametrize("sync", ("close", "event", "merge_edge",
+                                  "report"))
+@pytest.mark.parametrize("scheme", EXACT_SCHEMES)
+def test_fused_replica_sets_equal_batched(scheme, sync, keys, values,
+                                          monkeypatch):
+    # the fused engine records its replica pairs on the host; each way a
+    # sync is reached must leave grouper.replicas exactly the batched
+    # engine's dict of sets
+    if sync == "report":
+        assert (_report_replicas("fused", scheme, keys)
+                == _report_replicas("batched", scheme, keys))
+        return
+    src = Source(keys, arrival_rate=2e4, values=values)
+    if sync == "merge_edge":
+        topo, events = _two_stage(scheme), ()
+    else:
+        op = TopoWindowOp(agg="sum", value="payload", size=1_024)
+        topo = _topo(scheme, op)
+        # both events fall inside a feed and inside a pane
+        events = () if sync == "close" else (
+            ScopedEvent("agg", MembershipEvent(
+                at=2_100, workers=tuple(range(10)))),
+            ScopedEvent("agg", MembershipEvent(
+                at=4_700, workers=tuple(range(1, 10)))))
+    rb, close_b, events_b = _session_replicas("batched", topo, src, events,
+                                              monkeypatch)
+    rf, close_f, events_f = _session_replicas("fused", topo, src, events,
+                                              monkeypatch)
+    assert close_f == close_b
+    assert events_f == events_b
+    assert len(events_f) == len(events)
+    for eb, ef in zip(rb.edges, rf.edges):
+        assert ef.memory_overhead == eb.memory_overhead > 0
+
+
+@pytest.mark.parametrize("scheme", EXACT_SCHEMES)
+def test_replica_pairs_new_sum_to_memory_overhead(scheme, keys, values):
+    # every (key, worker) pair is counted new exactly once, on either edge
+    tel = Telemetry(enabled=True)
+    sess = SimulatorEngine(mode="fused").open(
+        _two_stage(scheme), arrival_rate=2e4, telemetry=tel)
+    src = Source(keys, arrival_rate=2e4, values=values)
+    for batch in src.iter_batches(batch_size=1_500):
+        sess.feed(batch)
+    rep = sess.close()
+    new = [c.value for c in tel.metrics
+           if c.name == "fused.replica_pairs_new"]
+    assert len(new) == len(rep.edges) == 2
+    assert sum(new) == sum(e.memory_overhead for e in rep.edges)
+    assert all(e.memory_overhead > 0 for e in rep.edges)
+    folds = [s for s in tel.tracer.spans if s.name == "fused.replica_fold"]
+    assert sum(s.args["new"] for s in folds) == sum(new)
+    assert all(0 <= s.args["new"] <= s.args["pairs"] for s in folds)
 
 
 # ---------------------------------------------------------------------------
@@ -389,7 +510,6 @@ def test_fused_falls_back_on_negative_keys():
 
 
 def test_fused_rejects_state_sink_in_host_modes(keys):
-    from repro.state import KeyedStateManager
     g = config_for("fg").build(4)
     mgr = KeyedStateManager(WindowOp(agg="count", size=100))
     with pytest.raises(ValueError, match="state_sink"):

@@ -73,7 +73,7 @@ def _scopes(scheme, has_pane):
              "pkg": ["route/ring", "route/choose"]}.get(
         scheme, ["route/ring", "route/tracker", "route/choose"])
     tail = ["pane/scatter", "pane/last"] if has_pane else []
-    return route + ["fifo", "replicas"] + tail
+    return route + ["fifo"] + tail
 
 
 @pytest.mark.parametrize("scheme", SCHEMES)
@@ -168,6 +168,25 @@ def test_pane_flush_children_nest_and_cover_the_flush(fish_run):
         assert scan.args["touched"] == got["pane_fed"]
         assert 0 < scan.args["live"] <= scan.args["touched"]
         assert scan.args["live"] == merge.args["entries"]
+
+
+def test_replica_fold_in_each_flush_and_each_paneless_launch(fish_spans):
+    flushes = [s for s in fish_spans if s.name == "fused.pane_flush"]
+    folds = [s for s in fish_spans if s.name == "fused.replica_fold"]
+    in_flush = [f for f in folds if any(_within(f, p) for p in flushes)]
+    assert len(in_flush) == len(flushes)  # one a flush, on its deduped pairs
+    # outside the flush's scan and every launch's readback
+    apart = [s for s in fish_spans if s.name in ("fused.pane_flush.scan",
+                                                  "fused.segment.readback")]
+    assert not any(_within(f, s) for f in folds for s in apart)
+    # the others fold a launch of the merge edge, which has no pane
+    segs = [s for s in fish_spans if s.name == "fused.segment"
+            and s.args["scheme"] == "fg"]
+    rest = [f for f in folds if f not in in_flush]
+    assert len(rest) == len(segs)
+    assert all(any(_within(f, s) for s in segs) for f in rest)
+    for f in folds:
+        assert 0 <= f.args["new"] <= f.args["pairs"]
 
 
 def test_session_emit_once_per_operator_feed(fish_spans):
@@ -281,8 +300,11 @@ def _bundle():
         ("fused.pane_slots", 1.1, 1.2, {"new": 3, "slots": 10}),
         ("fused.hash_fill", 0.05, 0.08, {"keys": 7}),
         ("fused.hash_fill", 1.05, 1.06, {"keys": 0}),
+        ("fused.replica_fold", 0.5, 0.52, {"pairs": 7, "new": 7}),
+        ("fused.replica_fold", 3.5, 3.53, {"pairs": 10, "new": 3}),
         ("session.emit", 11.0, 12.0, None),  # after the window
         ("fused.pane_slots", 11.1, 11.5, {"new": 0, "slots": 10}),
+        ("fused.replica_fold", 11.6, 11.7, {"pairs": 4, "new": 0}),
     ]
     return {"spans": spans, "window": (0.0, 10.0)}
 
@@ -295,6 +317,7 @@ READINGS = {
     "session_emit_ms_per_feed.sat": 200.0,
     "pane_slot_ms_per_feed.sat": 75.0,
     "hash_fill_ms_per_feed.sat": 20.0,
+    "replica_fold_ms_per_feed.sat": 25.0,
 }
 
 
